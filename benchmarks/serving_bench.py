@@ -12,6 +12,11 @@ the endpoint that last served the other one. Emits per-lane p50/p99
 latency and the warm-hit rate (from the env-held uses counter each
 serving call reports), which ``tools/bench_gate.py --serving`` gates on:
 warmth-aware routing must beat (or tie) random on warm-hit rate.
+
+The models are the ``@smoke`` (toy-size) configs. Every result names the
+platform that served it, and the run fails if they differ: an endpoint
+that could not get the accelerator and fell back to the CPU is an error,
+not a data point.
 """
 from __future__ import annotations
 
@@ -24,7 +29,7 @@ import numpy as np
 
 from .common import emit
 
-ARCHS = ("qwen1.5-0.5b", "mamba2-370m")
+ARCHS = ("qwen1.5-0.5b@smoke", "mamba2-370m@smoke")
 
 
 def _pct(sorted_vals, q: float) -> float:
@@ -39,7 +44,8 @@ def serving_lane(router: str, requests: int, *, n_endpoints: int = 2,
     """One fleet under one endpoint-router policy. Closed-loop clients:
     ``concurrency`` threads each submit-and-wait through the executor
     (executor.submit → submit_packed_batch → select_many is the routed
-    path under test). Returns (sorted latencies, warm-hit rate, req/s)."""
+    path under test). Returns (sorted latencies, warm-hit rate, req/s,
+    the set of platforms that served)."""
     from repro.core import FuncXClient, FuncXService
     from repro.core.endpoint import spawn_endpoint_process
     from repro.serve import fabric
@@ -71,11 +77,14 @@ def serving_lane(router: str, requests: int, *, n_endpoints: int = 2,
         # round-robin over the fleet — the deployment's prewarm step, and
         # identical in both lanes. The measured stream then gauges steady
         # -state routing quality, not the unavoidable first compiles.
+        platforms = set()
         for i, arch in enumerate(ARCHS):
             fid, ct = zoo[arch]
-            ex.submit(fid, {"tokens": prompts[0], "n_tokens": 2, "seed": 0},
-                      endpoint_id=eids[i % n_endpoints],
-                      container_type=ct).result(timeout=timeout)
+            out = ex.submit(fid, {"tokens": prompts[0], "n_tokens": 2,
+                                  "seed": 0},
+                            endpoint_id=eids[i % n_endpoints],
+                            container_type=ct).result(timeout=timeout)
+            platforms.add(out["platform"])
         lock = threading.Lock()
         lats, warm_hits = [], [0]
         counter = itertools.count()
@@ -94,6 +103,7 @@ def serving_lane(router: str, requests: int, *, n_endpoints: int = 2,
                 with lock:
                     lats.append(dt)
                     warm_hits[0] += bool(out["warm"])
+                    platforms.add(out["platform"])
 
         t0 = time.perf_counter()
         threads = [threading.Thread(target=closed_loop, daemon=True)
@@ -104,7 +114,8 @@ def serving_lane(router: str, requests: int, *, n_endpoints: int = 2,
             t.join()
         wall = time.perf_counter() - t0
         ex.shutdown()
-        return sorted(lats), warm_hits[0] / max(requests, 1), requests / wall
+        return (sorted(lats), warm_hits[0] / max(requests, 1),
+                requests / wall, platforms)
     finally:
         for p in procs:
             p.terminate()
@@ -124,15 +135,20 @@ def run(full: bool = False, tiny: bool = False) -> None:
     else:
         requests = 24
 
-    aware_lats, aware_rate, aware_rps = serving_lane("warming_aware",
-                                                     requests)
-    rand_lats, rand_rate, rand_rps = serving_lane("random", requests)
+    aware_lats, aware_rate, aware_rps, aware_on = serving_lane(
+        "warming_aware", requests)
+    rand_lats, rand_rate, rand_rps, rand_on = serving_lane("random", requests)
+    platforms = aware_on | rand_on
+    if len(platforms) != 1:
+        raise RuntimeError(f"endpoints served on different platforms: "
+                           f"{sorted(platforms)}")
 
     for label, lats, rate, rps in [
             ("aware", aware_lats, aware_rate, aware_rps),
             ("random", rand_lats, rand_rate, rand_rps)]:
         emit(f"serving/{label}/p50_ms", _pct(lats, 0.50) * 1e3,
-             f"requests={requests} archs={len(ARCHS)}")
+             f"requests={requests} archs={len(ARCHS)} "
+             f"platform={next(iter(platforms))}")
         emit(f"serving/{label}/p99_ms", _pct(lats, 0.99) * 1e3, "")
         emit(f"serving/{label}/warm_hit_rate", rate,
              f"req_per_s={rps:.2f}")

@@ -21,7 +21,7 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_reduced_config
+from repro.configs import get_config
 from repro.core import FuncXClient, FuncXService
 from repro.core.endpoint import spawn_endpoint_process
 from repro.data import DataRef
@@ -33,17 +33,19 @@ from repro.train import (
     train_warmth_key,
 )
 
-ARCH = "qwen1.5-0.5b"
+ARCH = "qwen1.5-0.5b@smoke"         # the toy size; a bare id is published width
 N_ENDPOINTS = 2
 ROUNDS = 3
 
 
 def main():
-    cfg = get_reduced_config(ARCH)
+    # The edge endpoints train on the accelerator, and a chip belongs to
+    # one process: the coordinator keeps its own (small) arrays on the CPU.
+    jax.config.update("jax_platforms", "cpu")
+    cfg = get_config(ARCH)
     model = get_model(cfg)
+    delta_nbytes = model.param_count() * np.dtype(np.float32).itemsize
     params = model.init(jax.random.PRNGKey(0))
-    delta_nbytes = sum(np.asarray(l).astype(np.float32).nbytes
-                       for l in jax.tree.leaves(params))
 
     service = FuncXService(heartbeat_timeout=2.0, shm=False)
     token = service.register_user("fl-coordinator")

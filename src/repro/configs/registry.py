@@ -37,9 +37,14 @@ _MODULES = {
 }
 
 ARCH_IDS: List[str] = list(_MODULES)
+SMOKE_SUFFIX = "@smoke"         # the name every reduced config carries
 
 
 def get_config(arch: str) -> ModelConfig:
+    """The published config of ``arch``; ``<arch>@smoke`` names its
+    reduced (toy-size) config instead."""
+    if arch.endswith(SMOKE_SUFFIX):
+        return get_reduced_config(arch[:-len(SMOKE_SUFFIX)])
     try:
         return _MODULES[arch].CONFIG
     except KeyError:
@@ -47,7 +52,10 @@ def get_config(arch: str) -> ModelConfig:
 
 
 def get_reduced_config(arch: str) -> ModelConfig:
-    return _MODULES[arch].reduced()
+    try:
+        return _MODULES[arch].reduced()
+    except KeyError:
+        raise KeyError(f"unknown arch {arch!r}; options: {ARCH_IDS}") from None
 
 
 @dataclass(frozen=True)

@@ -130,7 +130,10 @@ class Worker(threading.Thread):
             if self._killed:
                 return
             if isinstance(item, tuple) and item[0] is _WARMUP:
-                self.cache.get_or_build(item[1])
+                try:
+                    self.cache.get_or_build(item[1])
+                except Exception:           # noqa: BLE001 — a task of this
+                    pass                    # type rebuilds and reports it
                 if self.inbox.empty():
                     self.busy.clear()
                     self._notify()
@@ -142,9 +145,12 @@ class Worker(threading.Thread):
 
     def _execute(self, item: WorkItem) -> None:
         stamps = dict(item.stamps)
-        container, cold = self.cache.get_or_build(item.container_type)
-        stamps["worker_start"] = now()
+        container, cold = None, False
         try:
+            # a container build that raises (compile error, device out of
+            # memory) fails this task and leaves the worker serving
+            container, cold = self.cache.get_or_build(item.container_type)
+            stamps["worker_start"] = now()
             if self.slowdown:
                 time.sleep(self.slowdown)
             # Lazy unpack (DESIGN.md §5): the payload crossed every hop as

@@ -1,9 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
 These keep the *model* layout at the boundary (B, S, H, D) and handle layout
-transposition, head-dim padding to MXU-friendly multiples, and
-interpret-mode selection (interpret=True on CPU — executes the kernel body
-for correctness; compiled Mosaic on real TPU).
+transposition and head-dim padding to MXU-friendly multiples. Every call
+compiles to Mosaic for the TPU unless the caller passes ``interpret=True``
+(which executes the kernel body on any backend, for correctness tests); a
+compiled call on another backend fails in the Pallas lowering.
 """
 from __future__ import annotations
 
@@ -17,10 +18,6 @@ from .decode_attention import decode_attention_kernel
 from .flash_attention import flash_attention_kernel
 from .rglru_scan import rglru_scan_kernel
 from .ssd_scan import ssd_scan_kernel
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_last(x: jax.Array, multiple: int) -> Tuple[jax.Array, int]:
@@ -48,10 +45,8 @@ def flash_attention(
     softmax_scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _interpret_default()
     D = q.shape[-1]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     # pad head dim to an MXU-friendly multiple (zeros do not perturb scores)
@@ -83,10 +78,8 @@ def decode_attention(
     window: Optional[int] = None,
     softmax_scale: Optional[float] = None,
     block_s: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _interpret_default()
     D = q.shape[-1]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     q2, _ = _pad_last(q, 128)
@@ -113,10 +106,8 @@ def rglru(
     *,
     block_s: int = 256,
     block_w: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _interpret_default()
     return rglru_scan_kernel(a, b, block_s=block_s, block_w=block_w,
                              interpret=interpret)
 
@@ -129,10 +120,8 @@ def ssd(
     Cm: jax.Array,                # (B, S, H, N)
     *,
     chunk: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    if interpret is None:
-        interpret = _interpret_default()
     xt = jnp.moveaxis(x, 2, 1)     # (B, H, S, P)
     at = jnp.moveaxis(a, 2, 1)     # (B, H, S)
     Bt = jnp.moveaxis(Bm, 2, 1)

@@ -19,18 +19,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-try:                                     # jax >= 0.6 public API
-    from jax import shard_map
-except ImportError:                      # older jax: experimental module,
-    from jax.experimental.shard_map import (  # check_vma spelled check_rep
-        shard_map as _exp_shard_map,
-    )
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma, **kw)
 
 from ..configs import ModelConfig
 from ..sharding.rules import ShardCtx, spec_for

@@ -15,6 +15,7 @@ Stacked (scanned) layers are expressed by :func:`stack` which prepends a
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -60,14 +61,15 @@ def _init_leaf(spec: ParamSpec, key: jax.Array, dtype) -> jax.Array:
 
 
 def init_params(spec_tree: Any, key: jax.Array, dtype=jnp.float32) -> Any:
-    """Materialize parameters; each leaf is seeded by folding in its path."""
+    """Materialize parameters; each leaf is seeded by folding in a stable
+    hash of its path, so one key gives the same weights in every process."""
     leaves_with_paths = jax.tree_util.tree_flatten_with_path(
         spec_tree, is_leaf=_is_spec)[0]
     treedef = jax.tree_util.tree_structure(spec_tree, is_leaf=_is_spec)
     arrays = []
     for path, spec in leaves_with_paths:
         path_str = jax.tree_util.keystr(path)
-        leaf_key = jax.random.fold_in(key, hash(path_str) % (2**31 - 1))
+        leaf_key = jax.random.fold_in(key, zlib.crc32(path_str.encode()) % (2**31 - 1))
         arrays.append(_init_leaf(spec, leaf_key, dtype))
     return jax.tree_util.tree_unflatten(treedef, arrays)
 

@@ -1,8 +1,10 @@
 """Serving fabric (DESIGN.md §10): the jax_pallas model zoo behind funcX.
 
 Every ``(arch, step, shape-bucket)`` combination is one **warmth key** —
-``jit/<arch>/<step>/b<bucket>`` — used as the task's container type:
-workers build the jit-compiled executables (+ resident params) as the
+``jit/<arch>/<step>/b<bucket>`` — used as the task's container type.
+``<arch>`` names the model at its published width (``qwen1.5-0.5b``);
+``<arch>@smoke`` names its reduced toy-size config, which CPU runs use.
+Workers build the jit-compiled executables (+ resident params) as the
 container environment, so the first request per key pays the real
 ``jax.jit`` compile (the cold start the paper measures for containers)
 and the WarmCache advertises the key through the ordinary warm dicts.
@@ -22,7 +24,8 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
-from ..configs import ARCH_IDS, get_reduced_config
+from ..compile_cache import enable_compile_cache
+from ..configs import get_config
 from ..core.warming import ContainerRegistry, ContainerSpec
 
 JIT_PREFIX = "jit/"
@@ -78,31 +81,50 @@ def pad_to_bucket(tokens: np.ndarray) -> np.ndarray:
 # container build == jit compile (the real cold start)
 # ---------------------------------------------------------------------------
 
+PARAMS_SEED = 0                 # weights are random, made from this seed
+
+
+def build_steps(model, bucket: int):
+    """The jitted ``(prefill, decode)`` pair one environment serves a
+    bucket with: prefill leaves ``_DECODE_HORIZON`` slots of cache past
+    the prompt for decode to fill."""
+    import jax
+
+    from ..models.knobs import RunKnobs
+    from .serve_step import make_decode, make_prefill
+
+    knobs = RunKnobs(q_block=64, kv_block=64)
+    return (jax.jit(make_prefill(model, knobs=knobs,
+                                 cache_len=bucket + _DECODE_HORIZON)),
+            jax.jit(make_decode(model, knobs=knobs)))
+
+
 def _build_env(arch: str, step: str, bucket: int) -> Dict[str, Any]:
     """Build one serving environment: init params, jit-compile the step
     executables **eagerly** at the bucket shape — the build time the
-    WarmCache records is the actual compile cost."""
+    WarmCache records is the actual compile cost. Records the device the
+    params live on, which every served result reports."""
     import jax
     import jax.numpy as jnp
 
     from ..models import get_model
-    from ..models.knobs import RunKnobs
-    from .serve_step import make_decode, make_prefill
 
-    cfg = get_reduced_config(arch)
+    enable_compile_cache()
+    cfg = get_config(arch)
     model = get_model(cfg)
-    knobs = RunKnobs(q_block=64, kv_block=64)
-    params = model.init(jax.random.PRNGKey(0))
-    prefill = jax.jit(make_prefill(model, knobs=knobs,
-                                   cache_len=bucket + _DECODE_HORIZON))
-    decode = jax.jit(make_decode(model, knobs=knobs))
+    params = model.init(jax.random.PRNGKey(PARAMS_SEED))
+    prefill, decode = build_steps(model, bucket)
     probe = jnp.zeros((1, bucket), jnp.int32)
-    logits, cache = prefill(params, {"tokens": probe})
+    out = prefill(params, {"tokens": probe})
     if step != "prefill":                   # decode executable too
-        decode(params, cache, {"tokens": probe[:, :1]})
+        out = decode(params, out[1], {"tokens": probe[:, :1]})
+    jax.block_until_ready(out)
+    device, = jax.tree.leaves(params)[0].devices()
     return {"arch": arch, "step": step, "bucket": bucket, "cfg": cfg,
             "model": model, "params": params, "prefill": prefill,
-            "decode": decode, "uses": 0}
+            "decode": decode, "uses": 0,
+            "device": {"platform": device.platform,
+                       "device_kind": device.device_kind}}
 
 
 def _spec_for(container_type: str) -> ContainerSpec:
@@ -126,6 +148,12 @@ def install(registry: ContainerRegistry) -> ContainerRegistry:
 # registered funcX functions (module-level: resolvable by reference from
 # subprocess endpoints via plain pickle)
 # ---------------------------------------------------------------------------
+
+def _served_by(env) -> Dict[str, Any]:
+    """What every served result reports beside its output: the model,
+    the shape bucket and the device that ran it."""
+    return {"arch": env["arch"], "bucket": env["bucket"], **env["device"]}
+
 
 def serve_generate(data, env):
     """Batched generation inside the warm jit environment. Reports
@@ -151,7 +179,7 @@ def serve_generate(data, env):
         tok = sample(logits, sub, 0.0)
         outs.append(np.asarray(tok))
     return {"tokens": np.stack(outs, axis=1), "warm": uses > 0,
-            "arch": env["arch"], "bucket": env["bucket"]}
+            **_served_by(env)}
 
 
 def serve_prefill(data, env):
@@ -164,7 +192,7 @@ def serve_prefill(data, env):
                          jnp.int32)
     logits, _cache = env["prefill"](env["params"], {"tokens": tokens})
     return {"next_token": np.asarray(jnp.argmax(logits, axis=-1)),
-            "warm": uses > 0}
+            "warm": uses > 0, **_served_by(env)}
 
 
 def serve_decode(data, env):
@@ -179,21 +207,21 @@ def serve_decode(data, env):
     tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
     logits, _cache = env["decode"](env["params"], cache, {"tokens": tok})
     return {"next_token": np.asarray(jnp.argmax(logits, axis=-1)),
-            "warm": uses > 0}
+            "warm": uses > 0, **_served_by(env)}
 
 
 _STEP_FNS = {"generate": serve_generate, "prefill": serve_prefill,
              "decode": serve_decode}
 
 
-def register_zoo(client, archs=None, *, step: str = "generate"):
+def register_zoo(client, archs, *, step: str = "generate"):
     """Register the serving function once per arch with the service and
     return ``{arch: (function_id, container_type_for_bucket16)}`` — the
-    convenience map benches and examples drive the fabric through. The
-    per-request container type (= warmth key) still varies by shape
+    convenience map benches and examples drive the fabric through. A bare
+    arch id serves the published width; ``<arch>@smoke`` the toy size.
+    The per-request container type (= warmth key) still varies by shape
     bucket; pass ``container_type=jit_key(arch, step, shape_bucket(S))``
     at submit time for non-default prompts."""
-    archs = list(archs) if archs is not None else list(ARCH_IDS)
     fn = _STEP_FNS[step]
     out = {}
     for arch in archs:

@@ -128,14 +128,16 @@ _LOCAL_STATE: Dict[str, Any] = {}
 
 
 def _local_env(arch: str, seq: int, batch: int) -> Dict[str, Any]:
-    from ..configs import TrainConfig, get_reduced_config
+    from ..compile_cache import enable_compile_cache
+    from ..configs import TrainConfig, get_config
     from ..models import get_model
     from .train_step import make_train_step
 
     key = train_warmth_key(arch, seq)
     env = _LOCAL_STATE.get(key)
     if env is None:
-        cfg = get_reduced_config(arch)
+        enable_compile_cache()
+        cfg = get_config(arch)
         model = get_model(cfg)
         tc = TrainConfig(learning_rate=5e-3, warmup_steps=0,
                          total_steps=200)

@@ -8,7 +8,8 @@ import time
 
 import pytest
 
-from repro.core import ElasticStrategy, LocalProvider, SimCloudProvider, SimSlurmProvider, TaskLost, TcpListener
+from repro.core import (ContainerSpec, ElasticStrategy, LocalProvider, SimCloudProvider,
+                        SimSlurmProvider, TaskFailure, TaskLost, TcpListener)
 from repro.core.comms import TO_SERVICE
 from repro.core.endpoint import demo_sleep, demo_square
 from conftest import start_tcp_endpoint, wait_until
@@ -45,6 +46,28 @@ def test_all_managers_dead_then_lost(service, client):
     with pytest.raises(TaskLost):
         client.get_result(tid, timeout=30)
     agent.stop()
+
+
+def test_container_build_failure_fails_task_and_worker_survives(service, client):
+    """A container whose build raises (a compile error, the device out of
+    memory) fails its task with that error; the worker thread lives on
+    and serves the next task."""
+    def broken_build():
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+    service.register_container(ContainerSpec("broken", build=broken_build))
+    fid_broken = client.register_function(lambda d: d, container_type="broken")
+    fid_ok = client.register_function(lambda d: d + 1)
+    eid, agent = service.make_endpoint(client.token, "ep", n_managers=1,
+                                       workers_per_manager=1)
+    ex = client.executor(endpoint_id=eid)
+    try:
+        with pytest.raises(TaskFailure, match="RESOURCE_EXHAUSTED") as info:
+            ex.submit(fid_broken, 1).result(timeout=10)
+        assert "broken_build" in info.value.remote_traceback
+        assert ex.submit(fid_ok, 1).result(timeout=10) == 2
+    finally:
+        ex.shutdown(wait=False)
+        agent.stop()
 
 
 def test_disconnect_requeues_and_recovers(service, client):

@@ -172,3 +172,22 @@ def test_ssd_matches_model_chunked_scan():
     y2, s2 = ssd_scan(x, a, Bm, Cm, chunk=32)
     np.testing.assert_allclose(y1, y2, atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(s1, s2, atol=5e-4, rtol=5e-4)
+
+
+# ----------------------------------------------------- no silent interpret
+
+@pytest.mark.parametrize("call", [
+    lambda: ops.flash_attention(*[jnp.zeros((1, 64, 4, 64))] * 3,
+                                block_q=64, block_k=64),
+    lambda: ops.decode_attention(jnp.zeros((1, 1, 4, 64)),
+                                 *[jnp.zeros((1, 64, 4, 64))] * 2,
+                                 jnp.full((1,), 64), block_s=64),
+    lambda: ops.rglru(*[jnp.zeros((1, 64, 128))] * 2, block_s=64),
+    lambda: ops.ssd(jnp.zeros((1, 64, 2, 16)), jnp.zeros((1, 64, 2)),
+                    *[jnp.zeros((1, 64, 2, 16))] * 2, chunk=32),
+], ids=["flash_attention", "decode_attention", "rglru", "ssd"])
+def test_compiled_kernel_refuses_the_cpu(call):
+    """Interpret mode runs only when asked for: a compiled call off the
+    TPU fails instead of quietly running the interpreter."""
+    with pytest.raises(ValueError, match="interpret mode"):
+        call()
