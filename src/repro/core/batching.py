@@ -73,7 +73,6 @@ class SubmitCoalescer:
         self._stop = threading.Event()
         # gauges (submit-plane acceptance: flushes/task << 1 under storm)
         self.flushes = 0                     # ship() calls
-        self.entries_shipped = 0
         self._thread = threading.Thread(target=self._flush_loop, daemon=True,
                                         name="submit-coalescer")
         self._thread.start()
@@ -147,7 +146,6 @@ class SubmitCoalescer:
                 return
             self._ship(batch)
             self.flushes += 1
-            self.entries_shipped += len(batch)
             flushed += 1
 
 

@@ -79,6 +79,7 @@ from .protocol import (
     to_wire_parts,
 )
 from .routing import Router, RoutingContext, WarmthView, make_router
+from .spans import span
 from .tasks import now
 from .warming import ContainerRegistry
 from .worker import WorkItem, WorkResult
@@ -192,7 +193,6 @@ class ResultCoalescer:
         self.envelopes_sent = 0            # envelopes the channel accepted
         self.result_envelopes = 0          # ...of which carried ≥1 result
         self.results_sent = 0
-        self.acks_sent = 0
         self.envelopes_parked = 0          # refused by the link, queued for
         #                                    retransmission
         self._thread = threading.Thread(target=self._flush_loop, daemon=True,
@@ -273,15 +273,16 @@ class ResultCoalescer:
                 acks.append(self._acks.popleft())
             if not results and not acks:
                 return
-            # scatter-gather: large packed results ride behind the
-            # envelope as borrowed segments — no memcpy into it (§7)
-            env, segs = to_wire_parts(ResultBatch(results=results,
-                                                  acks=acks))
-            if self._send(env, segs):
+            with span("endpoint.flush"):
+                # scatter-gather: large packed results ride behind the
+                # envelope as borrowed segments — no memcpy into it (§7)
+                env, segs = to_wire_parts(ResultBatch(results=results,
+                                                      acks=acks))
+                sent = self._send(env, segs)
+            if sent:
                 self.envelopes_sent += 1
                 self.result_envelopes += 1 if results else 0
                 self.results_sent += len(results)
-                self.acks_sent += len(acks)
             else:
                 self._unsent.append((env, segs))
                 self.envelopes_parked += 1
@@ -307,7 +308,6 @@ class ResultCoalescer:
                 n = len(env.get("results", ()))
                 self.result_envelopes += 1 if n else 0
                 self.results_sent += n
-                self.acks_sent += len(env.get("acks", ()))
 
     @property
     def unsent_count(self) -> int:
@@ -422,7 +422,6 @@ class EndpointAgent:
         self.strategy = None
         # metrics
         self.tasks_received = 0
-        self.tasks_completed = 0
         self.tasks_reexecuted = 0
         self.speculative_dispatches = 0
 
@@ -501,21 +500,24 @@ class EndpointAgent:
             if wire is None:
                 continue
             env, _tag = wire
-            try:
-                msg = from_wire(env)
-            except (ProtocolError, SerializationError):
-                continue           # poison message: drop, keep the loop
-            if isinstance(msg, TaskBatch):
-                t_recv = now()
-                for spec in msg.tasks:
-                    spec.stamps["endpoint_recv"] = t_recv
-                self._enqueue_batch(msg.tasks)
-                # receipt ack rides the next result envelope (or its own
-                # immediately if none is in flight) — coalesced return path
-                self.coalescer.add_ack(
-                    Ack(task_ids=[s.task_id for s in msg.tasks],
-                        t_endpoint_recv=t_recv))
-            elif isinstance(msg, PeerGet):
+            with span("endpoint.recv"):
+                try:
+                    msg = from_wire(env)
+                except (ProtocolError, SerializationError):
+                    continue       # poison message: drop, keep the loop
+                if isinstance(msg, TaskBatch):
+                    t_recv = now()
+                    for spec in msg.tasks:
+                        spec.stamps["endpoint_recv"] = t_recv
+                    self._enqueue_batch(msg.tasks)
+                    # receipt ack rides the next result envelope (or its
+                    # own immediately if none is in flight) — coalesced
+                    # return path
+                    self.coalescer.add_ack(
+                        Ack(task_ids=[s.task_id for s in msg.tasks],
+                            t_endpoint_recv=t_recv))
+                    continue
+            if isinstance(msg, PeerGet):
                 # hub-relay serving: the service pulls a key from our
                 # store over the already-authenticated hub channel
                 self._serve_hub_get(msg)
@@ -622,50 +624,11 @@ class EndpointAgent:
                 while self._queue and len(batch) < 256:
                     batch.append(self._queue.popleft())
 
-            managers = self._alive_managers()
-            infos = [m.info() for m in managers]
-            by_id = {m.manager_id: m for m in managers}
-            # room derives from the same snapshot — Manager.room() would
-            # re-scan every worker a second time per cycle, and this loop
-            # is the serial feed stage (§7.2.3 hot path)
-            room = {inf.manager_id:
-                    max(inf.capacity + by_id[inf.manager_id].prefetch
-                        - inf.queued, 0)
-                    for inf in infos}
-            per_manager: Dict[str, list] = {}
-            leftovers = []
-            for spec in batch:
-                ctx = RoutingContext(warmth_key=spec.warmth_key or None,
-                                     container_type=spec.container_type)
-                target = self.router.route(ctx, infos)
-                if target is None or room.get(target, 0) <= 0:
-                    # the router's choice is saturated: requeue and retry
-                    # against a fresh snapshot (never override the policy
-                    # with first-fit — that would erase warm affinity)
-                    leftovers.append(spec)
-                    continue
-                room[target] -= 1
-                for inf in infos:          # keep the snapshot coherent
-                    if inf.manager_id == target:
-                        inf.queued += 1
-                        view = inf.warmth
-                        for key in ctx.warmth_keys:
-                            if view.warm_idle(key) > 0:
-                                view.note_pick(key)
-                                break
-                        inf.idle_workers = max(inf.idle_workers - 1, 0)
-                        break
-                try:
-                    item = self._make_item(spec)
-                except Exception as e:         # fn fetch / stage-in failure
-                    self._send_failure(spec.task_id,
-                                       f"staging: {type(e).__name__}: {e}")
-                    continue
-                self._dispatched_at[item.task_id] = (
-                    time.perf_counter(), spec, target)
-                per_manager.setdefault(target, []).append(item)
-            for mid, items in per_manager.items():
-                by_id[mid].submit_batch(items)
+            with span("endpoint.dispatch"):
+                leftovers, failed = self._route(batch)
+            # after the span: a failure may flush its envelope inline
+            for task_id, error in failed:
+                self._send_failure(task_id, error)
             if leftovers:
                 # saturated: park the overflow and wait for a completion
                 # (worker callbacks notify the cond) instead of polling —
@@ -678,6 +641,57 @@ class EndpointAgent:
                     self._queue_cond.wait(0.002)
                 self._dispatch_parked = False
 
+    def _route(self, batch: List[TaskSpec]
+               ) -> Tuple[List[TaskSpec], List[Tuple[str, str]]]:
+        """One routing pass over ``batch`` against one snapshot of the
+        managers; returns the specs that found no room and the
+        ``(task_id, error)`` of those that could not be staged."""
+        managers = self._alive_managers()
+        infos = [m.info() for m in managers]
+        by_id = {m.manager_id: m for m in managers}
+        # room derives from the same snapshot — Manager.room() would
+        # re-scan every worker a second time per cycle, and this loop
+        # is the serial feed stage (§7.2.3 hot path)
+        room = {inf.manager_id:
+                max(inf.capacity + by_id[inf.manager_id].prefetch
+                    - inf.queued, 0)
+                for inf in infos}
+        per_manager: Dict[str, list] = {}
+        leftovers, failed = [], []
+        for spec in batch:
+            ctx = RoutingContext(warmth_key=spec.warmth_key or None,
+                                 container_type=spec.container_type)
+            target = self.router.route(ctx, infos)
+            if target is None or room.get(target, 0) <= 0:
+                # the router's choice is saturated: requeue and retry
+                # against a fresh snapshot (never override the policy
+                # with first-fit — that would erase warm affinity)
+                leftovers.append(spec)
+                continue
+            room[target] -= 1
+            for inf in infos:          # keep the snapshot coherent
+                if inf.manager_id == target:
+                    inf.queued += 1
+                    view = inf.warmth
+                    for key in ctx.warmth_keys:
+                        if view.warm_idle(key) > 0:
+                            view.note_pick(key)
+                            break
+                    inf.idle_workers = max(inf.idle_workers - 1, 0)
+                    break
+            try:
+                item = self._make_item(spec)
+            except Exception as e:         # fn fetch / stage-in failure
+                failed.append((spec.task_id,
+                               f"staging: {type(e).__name__}: {e}"))
+                continue
+            self._dispatched_at[item.task_id] = (
+                time.perf_counter(), spec, target)
+            per_manager.setdefault(target, []).append(item)
+        for mid, items in per_manager.items():
+            by_id[mid].submit_batch(items)
+        return leftovers, failed
+
     def _on_result(self, manager_id: str, res: WorkResult) -> None:
         if not self._completed.add(res.task_id):
             return                 # duplicate (speculation / requeue) — drop
@@ -689,7 +703,6 @@ class EndpointAgent:
                 spec = disp[1]
                 self._observe_build(spec.warmth_key or spec.container_type,
                                     res.build_time)
-        self.tasks_completed += 1
         # a worker just freed: wake the dispatch loop iff it parked
         # overflow waiting for room (plain flag read keeps the common
         # case lock-free — grabbing the queue lock on every completion

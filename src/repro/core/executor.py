@@ -72,7 +72,6 @@ class FuncXExecutor:
                                          linger=linger,
                                          outstanding=self.outstanding)
         # gauges
-        self.tasks_submitted = 0               # tasks landed on the service
         self.tasks_cancelled = 0               # parked entries cancelled
 
     # ------------------------------------------------------------- submission
@@ -154,7 +153,6 @@ class FuncXExecutor:
             for tid, entry in zip(tids, live):
                 self._futures[tid] = entry[5]
             self._unwatched.extend(tids)
-            self.tasks_submitted += len(tids)
             self._ensure_harvester_locked()
         self._work_event.set()
 

@@ -72,9 +72,6 @@ class EndpointLine:
         #   ("" → endpoint runs no peer server; ResolvePeer answers no)
         # metrics
         self.dispatched = 0
-        self.task_envelopes = 0         # TaskBatch frames sent (gauge:
-        #                                 tasks per envelope → submit-side
-        #                                 batching efficiency, DESIGN.md §8)
         self.results_received = 0
         self.result_envelopes = 0       # ResultBatch frames (gauge: results
         #                                 per envelope → batching efficiency)
@@ -148,7 +145,6 @@ class ForwarderPool:
         self._threads: List[threading.Thread] = []
         # metrics (pool-wide; per-endpoint counts live on the lines)
         self.dispatched = 0
-        self.task_envelopes = 0
         self.results_received = 0
         self.result_envelopes = 0
         self.requeues = 0
@@ -316,9 +312,7 @@ class ForwarderPool:
                     line.next_send_at = t + line.send_rtt
                 line.sent_since_credit += len(specs)
                 line.dispatched += len(specs)
-                line.task_envelopes += 1
                 self.dispatched += len(specs)
-                self.task_envelopes += 1
             else:
                 # channel refused (disconnected / dropped): requeue in order
                 for spec in specs:

@@ -243,7 +243,6 @@ class Interchange:
         # metrics
         self.tasks_received = 0
         self.tasks_dispatched = 0
-        self.task_envelopes = 0
         self.results_forwarded = 0
         self.requeues = 0
         self.dedup_dropped = 0
@@ -584,7 +583,6 @@ class Interchange:
             line.sent_since_credit += len(specs)
             line.dispatched += len(specs)
         self.tasks_dispatched += len(specs)
-        self.task_envelopes += 1
         return True
 
     # --------------------------------------------------------- downstream recv

@@ -3,6 +3,9 @@
 Timestamps intentionally mirror the paper's latency decomposition (§7.1):
 t_s (service), t_f (forwarder), t_e (endpoint/manager queuing), t_w (worker
 execution) — `latency_breakdown()` reproduces Fig. 3 from any finished task.
+`t_w` splits into the seconds the worker waited on the device (the fabric's
+blocking reads, summed under ``DEVICE_WAIT`` by `spans.device_wait`) and the rest,
+its host work.
 """
 from __future__ import annotations
 
@@ -44,6 +47,11 @@ def now() -> float:
     return time.perf_counter()
 
 
+# Stamp entry holding a duration, not a time: the seconds the worker spent
+# in the fabric's blocking device→host reads (``fabric.fetch`` spans).
+DEVICE_WAIT = "device_wait"
+
+
 @dataclass
 class Task:
     function_id: str
@@ -74,11 +82,16 @@ class Task:
         t = self.t
         get = lambda a, b: max(t.get(b, 0.0) - t.get(a, 0.0), 0.0) \
             if a in t and b in t else float("nan")
+        t_w = get("worker_start", "worker_end")
+        # both parts as differences from t_w, so that they sum to it exactly
+        t_w_host = t_w - min(max(t.get(DEVICE_WAIT, 0.0), 0.0), t_w)
         return {
             "t_s": get("submit", "service_queued"),
             "t_f": get("service_queued", "endpoint_recv"),
             "t_e": get("endpoint_recv", "worker_start"),
-            "t_w": get("worker_start", "worker_end"),
+            "t_w": t_w,
+            "t_w_host": t_w_host,
+            "t_w_device": t_w - t_w_host,
             "t_r": get("worker_end", "result_stored"),
             "total": get("submit", "result_stored"),
         }

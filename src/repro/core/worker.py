@@ -12,6 +12,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
 from ..serialization import PackedBuffer
+from .spans import bind, span
 from .tasks import now
 from .warming import ContainerRegistry, WarmCache
 
@@ -153,17 +154,20 @@ class Worker(threading.Thread):
             stamps["worker_start"] = now()
             if self.slowdown:
                 time.sleep(self.slowdown)
-            # Lazy unpack (DESIGN.md §5): the payload crossed every hop as
-            # an opaque frame; this is its single decode, at the consumer,
-            # just before the call. The buffer caches the decoded object,
-            # so a speculative or requeued re-delivery costs no re-decode.
-            payload = item.payload
-            if isinstance(payload, PackedBuffer):
-                payload = payload.unpack()
-            if item.wants_env:
-                result = item.fn(payload, container.env)
-            else:
-                result = item.fn(payload)
+            with bind(item.task_id, stamps):
+                # Lazy unpack (DESIGN.md §5): the payload crossed every hop
+                # as an opaque frame; this is its single decode, at the
+                # consumer, just before the call. The buffer caches the
+                # decoded object, so a speculative or requeued re-delivery
+                # costs no re-decode.
+                with span("worker.unpack"):
+                    payload = item.payload
+                    if isinstance(payload, PackedBuffer):
+                        payload = payload.unpack()
+                if item.wants_env:
+                    result = item.fn(payload, container.env)
+                else:
+                    result = item.fn(payload)
             status, error, tb = "SUCCESS", None, ""
         except Exception as e:              # noqa: BLE001 — remote fault
             result = None

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..compile_cache import enable_compile_cache
 from ..configs import get_config
+from ..core.spans import device_wait, span
 from ..core.warming import ContainerRegistry, ContainerSpec
 
 JIT_PREFIX = "jit/"
@@ -155,29 +156,49 @@ def _served_by(env) -> Dict[str, Any]:
     return {"arch": env["arch"], "bucket": env["bucket"], **env["device"]}
 
 
+# Each served function runs as three kinds of leaf span (core/spans.py):
+# ``fabric.put`` (the prompt to the device), ``fabric.dispatch`` (enqueue
+# the jitted steps and the sampling) and ``fabric.fetch`` (one blocking
+# device→host read, whose seconds are the task's device wait).
+
+def _put(data):
+    """The prompt, padded to its shape bucket, on the device."""
+    import jax.numpy as jnp
+
+    with span("fabric.put"):
+        return jnp.asarray(pad_to_bucket(np.asarray(data["tokens"])),
+                           jnp.int32)
+
+
+def _fetch(x) -> np.ndarray:
+    """One host round trip: wait for ``x`` and copy it to the host."""
+    with device_wait("fabric.fetch"):
+        return np.asarray(x)
+
+
 def serve_generate(data, env):
     """Batched generation inside the warm jit environment. Reports
     ``warm`` from an env-held uses counter, so clients can measure the
     warm-hit rate without reaching into worker internals."""
     import jax
-    import jax.numpy as jnp
 
     from .sampler import sample
 
     uses, env["uses"] = env["uses"], env["uses"] + 1
-    tokens = jnp.asarray(pad_to_bucket(np.asarray(data["tokens"])),
-                         jnp.int32)
+    tokens = _put(data)
     n_new = int(data.get("n_tokens", 4))
-    logits, cache = env["prefill"](env["params"], {"tokens": tokens})
-    key = jax.random.PRNGKey(int(data.get("seed", 0)))
-    tok = sample(logits, key, 0.0)
-    outs = [np.asarray(tok)]
+    with span("fabric.dispatch"):
+        logits, cache = env["prefill"](env["params"], {"tokens": tokens})
+        key = jax.random.PRNGKey(int(data.get("seed", 0)))
+        tok = sample(logits, key, 0.0)
+    outs = [_fetch(tok)]
     for _ in range(n_new - 1):
-        key, sub = jax.random.split(key)
-        logits, cache = env["decode"](env["params"], cache,
-                                      {"tokens": tok[:, None]})
-        tok = sample(logits, sub, 0.0)
-        outs.append(np.asarray(tok))
+        with span("fabric.dispatch"):
+            key, sub = jax.random.split(key)
+            logits, cache = env["decode"](env["params"], cache,
+                                          {"tokens": tok[:, None]})
+            tok = sample(logits, sub, 0.0)
+        outs.append(_fetch(tok))
     return {"tokens": np.stack(outs, axis=1), "warm": uses > 0,
             **_served_by(env)}
 
@@ -188,10 +209,11 @@ def serve_prefill(data, env):
     import jax.numpy as jnp
 
     uses, env["uses"] = env["uses"], env["uses"] + 1
-    tokens = jnp.asarray(pad_to_bucket(np.asarray(data["tokens"])),
-                         jnp.int32)
-    logits, _cache = env["prefill"](env["params"], {"tokens": tokens})
-    return {"next_token": np.asarray(jnp.argmax(logits, axis=-1)),
+    tokens = _put(data)
+    with span("fabric.dispatch"):
+        logits, _cache = env["prefill"](env["params"], {"tokens": tokens})
+        next_token = jnp.argmax(logits, axis=-1)
+    return {"next_token": _fetch(next_token),
             "warm": uses > 0, **_served_by(env)}
 
 
@@ -201,12 +223,14 @@ def serve_decode(data, env):
     import jax.numpy as jnp
 
     uses, env["uses"] = env["uses"], env["uses"] + 1
-    tokens = jnp.asarray(pad_to_bucket(np.asarray(data["tokens"])),
-                         jnp.int32)
-    logits, cache = env["prefill"](env["params"], {"tokens": tokens})
-    tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-    logits, _cache = env["decode"](env["params"], cache, {"tokens": tok})
-    return {"next_token": np.asarray(jnp.argmax(logits, axis=-1)),
+    tokens = _put(data)
+    with span("fabric.dispatch"):
+        logits, cache = env["prefill"](env["params"], {"tokens": tokens})
+        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        logits, _cache = env["decode"](env["params"], cache,
+                                       {"tokens": tok})
+        next_token = jnp.argmax(logits, axis=-1)
+    return {"next_token": _fetch(next_token),
             "warm": uses > 0, **_served_by(env)}
 
 

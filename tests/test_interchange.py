@@ -201,7 +201,11 @@ def test_upstream_cut_parks_results_and_retransmits(relay):
     svc, client, ix = relay
     prov, _ = add_leaves(ix, 1)
     try:
-        fid = client.register_function(lambda d: d["i"] * 2)
+        # each task outlasts the moment of the cut, so no result can reach
+        # the service before it: every one is produced into the outage or
+        # after, and comes back only over the re-registered link
+        fid = client.register_function(
+            lambda d: __import__("time").sleep(0.3) or d["i"] * 2)
         ids = client.batch_run([(fid, ix.endpoint_id, {"i": i})
                                 for i in range(10)])
         assert wait_until(lambda: ix.backlog_peak >= 1 or
