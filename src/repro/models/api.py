@@ -69,25 +69,27 @@ class Model:
         return total - inactive_per_layer * self.cfg.n_layers
 
     # ---- computations ------------------------------------------------------
-    # Parameters are kept in ``param_dtype`` (fp32 master); computation casts
-    # them to the activation dtype once at entry (mixed precision).
-    def _cast(self, params):
+    def compute_params(self, params):
+        """The weights as every computation reads them: float leaves in the
+        activation dtype (mixed precision). Training keeps ``param_dtype``
+        masters and each step casts them on entry; a serving environment
+        stores this tree, so the cast in its steps leaves no op."""
         return P.cast_floats(params, jnp.dtype(self.cfg.dtype))
 
     def loss(self, params, batch, ctx: ShardCtx = ShardCtx(),
              knobs: RunKnobs = DEFAULT_KNOBS, z_loss: float = 0.0):
-        return self.mod.loss_fn(self.cfg, self._cast(params), batch, ctx,
-                                knobs, z_loss)
+        return self.mod.loss_fn(self.cfg, self.compute_params(params), batch,
+                                ctx, knobs, z_loss)
 
     def prefill(self, params, batch, ctx: ShardCtx = ShardCtx(),
                 knobs: RunKnobs = DEFAULT_KNOBS, cache_len=None):
-        return self.mod.prefill(self.cfg, self._cast(params), batch, ctx,
-                                knobs, cache_len=cache_len)
+        return self.mod.prefill(self.cfg, self.compute_params(params), batch,
+                                ctx, knobs, cache_len=cache_len)
 
     def decode_step(self, params, cache, batch, ctx: ShardCtx = ShardCtx(),
                     knobs: RunKnobs = DEFAULT_KNOBS):
-        return self.mod.decode_step(self.cfg, self._cast(params), cache,
-                                    batch, ctx, knobs)
+        return self.mod.decode_step(self.cfg, self.compute_params(params),
+                                    cache, batch, ctx, knobs)
 
     # ---- caches ------------------------------------------------------------
     def init_cache(self, batch: int, max_seq: int, dtype=None, **kw):

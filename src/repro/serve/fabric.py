@@ -4,8 +4,10 @@ Every ``(arch, step, shape-bucket)`` combination is one **warmth key** —
 ``jit/<arch>/<step>/b<bucket>`` — used as the task's container type.
 ``<arch>`` names the model at its published width (``qwen1.5-0.5b``);
 ``<arch>@smoke`` names its reduced toy-size config, which CPU runs use.
-Workers build the jit-compiled executables (+ resident params) as the
-container environment, so the first request per key pays the real
+Workers build the jit-compiled executables as the container environment,
+with the weights resident in the compute dtype the steps read them in
+(``Model.compute_params``: no fp32 master is kept, and no step casts a
+weight), so the first request per key pays the real
 ``jax.jit`` compile (the cold start the paper measures for containers)
 and the WarmCache advertises the key through the ordinary warm dicts.
 Routing — federation and manager tier alike — then steers requests for a
@@ -101,10 +103,11 @@ def build_steps(model, bucket: int):
 
 
 def _build_env(arch: str, step: str, bucket: int) -> Dict[str, Any]:
-    """Build one serving environment: init params, jit-compile the step
-    executables **eagerly** at the bucket shape — the build time the
-    WarmCache records is the actual compile cost. Records the device the
-    params live on, which every served result reports."""
+    """Build one serving environment: init params and cast them once to
+    the compute dtype, jit-compile the step executables **eagerly** at the
+    bucket shape — the build time the WarmCache records is the actual
+    compile cost. Records the device the params live on, which every
+    served result reports."""
     import jax
     import jax.numpy as jnp
 
@@ -113,7 +116,9 @@ def _build_env(arch: str, step: str, bucket: int) -> Dict[str, Any]:
     enable_compile_cache()
     cfg = get_config(arch)
     model = get_model(cfg)
-    params = model.init(jax.random.PRNGKey(PARAMS_SEED))
+    # one jitted cast: eagerly, each distinct leaf shape compiles its own
+    params = jax.jit(model.compute_params)(
+        model.init(jax.random.PRNGKey(PARAMS_SEED)))
     prefill, decode = build_steps(model, bucket)
     probe = jnp.zeros((1, bucket), jnp.int32)
     out = prefill(params, {"tokens": probe})

@@ -4,10 +4,13 @@ model step, at the reduced ``@smoke`` size on the CPU. This is the CPU
 rehearsal of ``chip_smoke.py``, which runs the same path at the
 published width on a TPU."""
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -81,6 +84,56 @@ def test_served_step_reports_its_device(service, client, step):
         agent.stop()
     assert out["next_token"].shape == (1,) and not out["warm"]
     assert (out["arch"], out["bucket"], out["platform"]) == (SMOKE, 16, "cpu")
+
+
+@pytest.fixture(scope="module")
+def served():
+    """A built serving environment (both steps compiled) beside the fp32
+    masters that the same seed initialises."""
+    env = fabric._build_env(SMOKE, "decode", 16)
+    masters = env["model"].init(jax.random.PRNGKey(fabric.PARAMS_SEED))
+    batch = {"tokens": jnp.asarray(np.arange(1, 17, dtype=np.int32)[None])}
+    return env, masters, batch
+
+
+def test_served_weights_are_held_in_the_compute_dtype(served):
+    env, masters, _ = served
+    leaves = jax.tree.leaves(env["params"])
+    assert len(leaves) == len(jax.tree.leaves(masters))
+    assert {a.dtype for a in leaves} == {jnp.dtype(env["cfg"].dtype)}
+    assert {a.dtype for a in jax.tree.leaves(masters)} == {np.dtype("float32")}
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_served_weights_give_the_masters_logits_bit_for_bit(served, step):
+    """Casting once at build gives the weights the step casts from fp32
+    masters on every call, so the same jitted step returns equal logits."""
+    env, masters, batch = served
+    outs = []
+    for params in (env["params"], masters):
+        logits, cache = env["prefill"](params, batch)
+        if step == "decode":
+            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            logits, _ = env["decode"](params, cache, {"tokens": tok})
+        outs.append(np.asarray(logits))
+    np.testing.assert_array_equal(*outs)
+
+
+def _weight_casts(env, params, batch) -> int:
+    """``convert`` ops in the lowered prefill step whose operand is a whole
+    f32 argument of the step's entry function, i.e. a weight leaf."""
+    text = env["prefill"].lower(params, batch).as_text()
+    main = text.split("func.func public @main", 1)[1].split("func.func")[0]
+    return len(re.findall(
+        r"stablehlo\.convert %arg\d+ : \(tensor<[0-9x]*xf32>\)", main))
+
+
+def test_served_step_casts_no_weight(served):
+    """The mechanism engages: the served tree lowers with no per-step cast
+    of a weight, where the fp32 masters cost one for each float leaf."""
+    env, masters, batch = served
+    assert _weight_casts(env, env["params"], batch) == 0
+    assert _weight_casts(env, masters, batch) >= len(jax.tree.leaves(masters))
 
 
 def test_chip_smoke_refuses_the_cpu():

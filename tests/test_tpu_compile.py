@@ -79,11 +79,13 @@ def test_flash_attention_compiles_at_qwen_prefill_width(one_chip, seq, block):
 @pytest.fixture(scope="module")
 def qwen_steps(one_chip):
     """The published qwen1.5-0.5b prefill/decode pair, built as the fabric
-    builds it, with abstract params and inputs on the described chip."""
+    builds it, with abstract params in the compute dtype the fabric keeps
+    them in, and inputs, on the described chip."""
     model = get_model(get_config(QWEN))
     bucket = 64
     prefill, decode = fabric.build_steps(model, bucket)
-    params = _on(one_chip, model.abstract_params())
+    params = _on(one_chip, jax.eval_shape(model.compute_params,
+                                          model.abstract_params()))
     batch = _on(one_chip, {"tokens": jax.ShapeDtypeStruct((1, bucket),
                                                           jnp.int32)})
     _, cache = jax.eval_shape(prefill, params, batch)
