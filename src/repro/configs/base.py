@@ -26,16 +26,47 @@ class MoEConfig:
     router_jitter: float = 0.0
     # load-balancing auxiliary loss weight (Switch/GShard style)
     aux_loss_weight: float = 0.01
+    # top-k gate weights: renormalised to sum to 1, then scaled
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # shared experts every token takes: one SwiGLU of width
+    # n_shared_experts * d_ff_expert
+    n_shared_experts: int = 0
+    # leading layers with a dense SwiGLU of ModelConfig.d_ff in place of
+    # the expert layer
+    first_k_dense: int = 0
+    # the routed experts this chip holds, ids expert_first ..
+    # expert_first + experts_held - 1 (expert parallelism); None: all
+    experts_held: Optional[int] = None
+    expert_first: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
 class MLAConfig:
     """Multi-head latent attention (DeepSeek-V2 / MiniCPM3 style)."""
-    q_lora_rank: int
+    q_lora_rank: Optional[int]      # None: one full-rank query projection
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    # the rope dims rotate adjacent pairs (DeepSeek-V2) instead of halves
+    rope_interleaved: bool = False
+    yarn: Optional[YaRNConfig] = None
 
 
 @dataclass(frozen=True)

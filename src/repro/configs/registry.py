@@ -11,6 +11,7 @@ from .base import ModelConfig, ShapeConfig
 from .shapes import SHAPES, get_shape
 
 from . import (
+    deepseek_v2_lite,
     granite_moe_1b_a400m,
     llama4_scout_17b_a16e,
     mamba2_370m,
@@ -37,25 +38,40 @@ _MODULES = {
 }
 
 ARCH_IDS: List[str] = list(_MODULES)
+# served beside the grid: resolvable by name, not among its 40 cells
+_SERVED = {
+    "deepseek-v2-lite": deepseek_v2_lite,
+}
 SMOKE_SUFFIX = "@smoke"         # the name every reduced config carries
+
+
+def _module(arch: str):
+    try:
+        return {**_MODULES, **_SERVED}[arch]
+    except KeyError:
+        raise KeyError(f"unknown arch {arch!r}; options: "
+                       f"{ARCH_IDS + list(_SERVED)}") from None
 
 
 def get_config(arch: str) -> ModelConfig:
     """The published config of ``arch``; ``<arch>@smoke`` names its
-    reduced (toy-size) config instead."""
+    reduced (toy-size) config instead, and ``<arch>@<share>`` one chip's
+    share of a stated deployment (the module's ``SHARES``)."""
     if arch.endswith(SMOKE_SUFFIX):
         return get_reduced_config(arch[:-len(SMOKE_SUFFIX)])
-    try:
-        return _MODULES[arch].CONFIG
-    except KeyError:
-        raise KeyError(f"unknown arch {arch!r}; options: {ARCH_IDS}") from None
+    base, _, share = arch.partition("@")
+    module = _module(base)
+    if not share:
+        return module.CONFIG
+    shares = getattr(module, "SHARES", {})
+    if share not in shares:
+        raise KeyError(f"unknown share {share!r} of {base!r}; options: "
+                       f"{sorted(shares)}")
+    return shares[share]
 
 
 def get_reduced_config(arch: str) -> ModelConfig:
-    try:
-        return _MODULES[arch].reduced()
-    except KeyError:
-        raise KeyError(f"unknown arch {arch!r}; options: {ARCH_IDS}") from None
+    return _module(arch).reduced()
 
 
 @dataclass(frozen=True)

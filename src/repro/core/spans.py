@@ -16,6 +16,8 @@ clock.
 
 Spans are leaves: none opens inside another on one thread, so the host
 event covering a device gap names the phase, not an enclosing span.
+:func:`stamp` adds a time or a count of the function's own to the bound
+task's stamps (the fabric's first token and token count).
 """
 from __future__ import annotations
 
@@ -51,6 +53,14 @@ def bind(task_id: str, stamps: Dict[str, float]) -> Iterator[None]:
         yield
     finally:
         _local.task = None
+
+
+def stamp(name: str, value: Optional[float] = None) -> None:
+    """Stamp the bound task: ``name`` is ``value``, or now on
+    :func:`tasks.now`'s clock. Nothing where no task is bound."""
+    task = _local.task
+    if task is not None:
+        task.stamps[name] = now() if value is None else value
 
 
 class span:

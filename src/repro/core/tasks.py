@@ -5,7 +5,9 @@ t_s (service), t_f (forwarder), t_e (endpoint/manager queuing), t_w (worker
 execution) — `latency_breakdown()` reproduces Fig. 3 from any finished task.
 `t_w` splits into the seconds the worker waited on the device (the fabric's
 blocking reads, summed under ``DEVICE_WAIT`` by `spans.device_wait`) and the rest,
-its host work.
+its host work. A generating function also stamps its first token
+(``FIRST_TOKEN``) and its token count (``TOKENS``): time to first token and
+seconds per later token.
 """
 from __future__ import annotations
 
@@ -50,6 +52,10 @@ def now() -> float:
 # Stamp entry holding a duration, not a time: the seconds the worker spent
 # in the fabric's blocking device→host reads (``fabric.fetch`` spans).
 DEVICE_WAIT = "device_wait"
+# Stamp entries of a generating function: the moment its first token's
+# fetch returned (a time), and how many tokens it fetched (a count).
+FIRST_TOKEN = "first_token"
+TOKENS = "tokens"
 
 
 @dataclass
@@ -85,6 +91,9 @@ class Task:
         t_w = get("worker_start", "worker_end")
         # both parts as differences from t_w, so that they sum to it exactly
         t_w_host = t_w - min(max(t.get(DEVICE_WAIT, 0.0), 0.0), t_w)
+        n = t.get(TOKENS, 0)
+        t_w_next = (get(FIRST_TOKEN, "worker_end") / (n - 1) if n > 1
+                    else float("nan"))
         return {
             "t_s": get("submit", "service_queued"),
             "t_f": get("service_queued", "endpoint_recv"),
@@ -92,6 +101,10 @@ class Task:
             "t_w": t_w,
             "t_w_host": t_w_host,
             "t_w_device": t_w - t_w_host,
+            # generation: worker-side time to the first token, and seconds
+            # per later token
+            "t_w_first": get("worker_start", FIRST_TOKEN),
+            "t_w_next": t_w_next,
             "t_r": get("worker_end", "result_stored"),
             "total": get("submit", "result_stored"),
         }

@@ -64,9 +64,10 @@ class Model:
         m = self.cfg.moe
         if m is None:
             return total
-        inactive_per_layer = 3 * (m.n_experts - m.top_k) * \
+        inactive_per_layer = 3 * (m.n_held - min(m.top_k, m.n_held)) * \
             self.cfg.d_model * m.d_ff_expert
-        return total - inactive_per_layer * self.cfg.n_layers
+        return total - inactive_per_layer * (self.cfg.n_layers
+                                             - m.first_k_dense)
 
     # ---- computations ------------------------------------------------------
     def compute_params(self, params):

@@ -9,10 +9,12 @@ memory form (cache is rank·S instead of 2·H·hd·S).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..configs import ModelConfig
@@ -54,10 +56,17 @@ def _mla_spec(cfg: ModelConfig) -> dict:
     m = cfg.mla
     d, H = cfg.d_model, cfg.n_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"wq": ParamSpec((d, H * qk), ("embed", "heads_dim"), "scaled_normal")}
+    else:
+        q = {
+            "w_dq": ParamSpec((d, m.q_lora_rank), ("embed", "mla_rank"), "scaled_normal"),
+            "q_norm": ParamSpec((m.q_lora_rank,), ("mla_rank",), "zeros"),
+            "w_uq": ParamSpec((m.q_lora_rank, H * qk), ("mla_rank", "heads_dim"),
+                              "scaled_normal"),
+        }
     return {
-        "w_dq": ParamSpec((d, m.q_lora_rank), ("embed", "mla_rank"), "scaled_normal"),
-        "q_norm": ParamSpec((m.q_lora_rank,), ("mla_rank",), "zeros"),
-        "w_uq": ParamSpec((m.q_lora_rank, H * qk), ("mla_rank", "heads_dim"), "scaled_normal"),
+        **q,
         "w_dkv": ParamSpec((d, m.kv_lora_rank + m.qk_rope_head_dim),
                            ("embed", "mla_rank"), "scaled_normal"),
         "kv_norm": ParamSpec((m.kv_lora_rank,), ("mla_rank",), "zeros"),
@@ -206,15 +215,79 @@ def attn_decode(
 # MLA
 # ---------------------------------------------------------------------------
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, base: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(base)))
+
+
+def mla_rope_freqs(cfg: ModelConfig) -> np.ndarray:
+    """(rope_dim/2,) inverse frequencies of the MLA rope dims. With YaRN,
+    the interpolated (``/ factor``) and extrapolated bands are blended by
+    a linear ramp between the correction dims of ``beta_fast`` (floor)
+    and ``beta_slow`` (ceil)."""
+    m = cfg.mla
+    dim, base = m.qk_rope_head_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    y = m.yarn
+    if y is None:
+        return extra
+    inter = 1.0 / (y.factor * base ** (np.arange(0, dim, 2, dtype=np.float32)
+                                       / dim))
+    orig = y.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(y.beta_fast, dim, base, orig)), 0)
+    high = min(math.ceil(_yarn_correction_dim(y.beta_slow, dim, base, orig)),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``qk_head_dim ** -0.5``, times YaRN's ``mscale(factor,
+    mscale_all_dim)`` squared."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        scale *= yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array):
+    """Rotate the rope dims ``x`` (B, S, H, rope_dim). Interleaved pairs
+    are first laid out as halves, the layout the rotation (and so the
+    cache) holds them in."""
+    m = cfg.mla
+    if m.rope_interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    if m.yarn is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    y = m.yarn
+    return apply_rope(x, positions, cfg.rope_theta,
+                      inv_freq=jnp.asarray(mla_rope_freqs(cfg)),
+                      mscale=yarn_mscale(y.factor, y.mscale)
+                      / yarn_mscale(y.factor, y.mscale_all_dim))
+
+
 def _mla_q(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array):
     m, H = cfg.mla, cfg.n_heads
     B, S, _ = h.shape
-    dq = rms_norm(jnp.einsum("bsd,dr->bsr", h, p["w_dq"]), p["q_norm"],
-                  cfg.norm_eps)
-    q = jnp.einsum("bsr,rk->bsk", dq, p["w_uq"]).reshape(
-        B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if m.q_lora_rank is None:
+        q = jnp.einsum("bsd,dk->bsk", h, p["wq"])
+    else:
+        dq = rms_norm(jnp.einsum("bsd,dr->bsr", h, p["w_dq"]), p["q_norm"],
+                      cfg.norm_eps)
+        q = jnp.einsum("bsr,rk->bsk", dq, p["w_uq"])
+    q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope = q[..., :m.qk_nope_head_dim]
-    q_pe = apply_rope(q[..., m.qk_nope_head_dim:], positions, cfg.rope_theta)
+    q_pe = _mla_rope(cfg, q[..., m.qk_nope_head_dim:], positions)
     return q_nope, q_pe
 
 
@@ -222,11 +295,12 @@ def _mla_latents(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array):
     m = cfg.mla
     dkv = jnp.einsum("bsd,dr->bsr", h, p["w_dkv"])
     c_kv = rms_norm(dkv[..., :m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_pe = apply_rope(dkv[..., None, m.kv_lora_rank:], positions,
-                      cfg.rope_theta)[:, :, 0]            # (B, S, rope_dim)
+    k_pe = _mla_rope(cfg, dkv[..., None, m.kv_lora_rank:],
+                     positions)[:, :, 0]                  # (B, S, rope_dim)
     return c_kv, k_pe
 
 
+@jax.named_scope("mla")
 def mla_full(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array,
              ctx: ShardCtx, knobs: RunKnobs, *, return_kv: bool = False):
     m, H = cfg.mla, cfg.n_heads
@@ -247,6 +321,7 @@ def mla_full(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array,
     v = ctx.constrain(v, ("act_batch", "act_seq", "act_heads", None))
     out = chunked_attention(q, k, v.astype(q.dtype), causal=True,
                             q_block=knobs.q_block, kv_block=knobs.kv_block,
+                            softmax_scale=mla_softmax_scale(cfg),
                             unroll=not knobs.scan_layers)
     y = jnp.einsum("bsk,kd->bsd", out.reshape(B, S, -1), p["wo"])
     if return_kv:
@@ -254,6 +329,7 @@ def mla_full(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array,
     return y
 
 
+@jax.named_scope("mla")
 def mla_decode(cfg: ModelConfig, p: dict, h: jax.Array, cache: dict,
                pos: jax.Array, lengths: jax.Array, ctx: ShardCtx):
     m, H = cfg.mla, cfg.n_heads
@@ -278,8 +354,7 @@ def mla_decode(cfg: ModelConfig, p: dict, h: jax.Array, cache: dict,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bqhe,bse->bhqs", q_pe, k_pe,
                       preferred_element_type=jnp.float32))
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    s = s * scale
+    s = s * mla_softmax_scale(cfg)
     S = c_kv.shape[1]
     mask = jnp.arange(S)[None] < lengths[:, None]        # (B, S)
     s = jnp.where(mask[:, None, None], s, NEG_INF)

@@ -36,12 +36,17 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq=None, mscale: float = 1.0) -> jax.Array:
+    """x: (B, S, H, D); positions: (B, S) int32. ``inv_freq`` (D/2,)
+    replaces the plain frequencies of ``theta`` (YaRN), and ``mscale``
+    scales cos and sin."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                                   # (D/2,)
+    inv = rope_freqs(d, theta) if inv_freq is None else inv_freq  # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * inv         # (B, S, D/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

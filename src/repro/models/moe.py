@@ -1,15 +1,24 @@
 """Mixture-of-experts layer.
 
-Two execution paths sharing one core algorithm (scatter/gather token
-dispatch with per-rank capacity — no giant one-hot dispatch einsums):
+One core (:func:`_moe_core`) serves both execution paths. It is told which
+routed experts it holds (``e_first`` and the leading axis of the expert
+weights), routes every token over all ``n_experts``, computes the part of
+the result its own experts give, and adds the shared experts:
 
 - **EP path** (production): wrapped in ``shard_map``; experts are sharded
   over the ``model`` mesh axis (expert parallelism), tokens are replicated
-  over ``model`` and sharded over batch axes. Each rank dispatches only to
-  its local experts and the partial outputs are ``psum``-combined — the
+  over ``model`` and sharded over batch axes. Each rank computes its local
+  experts and the partial outputs are ``psum``-combined — the
   TPU-idiomatic equivalent of the all-to-all in GPU MoE systems.
-- **Local path** (single device / smoke tests): identical math with
-  ``E_local == E`` and no collectives.
+- **Local path**: no collectives. The experts held are those of the
+  config (``MoEConfig.experts_held`` from ``expert_first``; all of them
+  by default): a chip's share of an expert-parallel deployment runs this
+  path, and its partial result is what goes on to the next layer.
+
+Serving is dropless: the tokens routed to a held expert are sorted by
+expert and multiplied as one grouped product (``lax.ragged_dot``).
+Training may instead ask for the capacity path (per-expert buffers of
+``capacity_factor`` slots, overflow dropped).
 
 Returns the layer output plus the Switch-style load-balancing auxiliary loss.
 """
@@ -24,18 +33,27 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs import ModelConfig
 from ..sharding.rules import ShardCtx, spec_for
+from .common import swiglu
 from .params import ParamSpec
 
 
 def moe_spec(cfg: ModelConfig) -> dict:
     m = cfg.moe
-    d, e, f = cfg.d_model, m.n_experts, m.d_ff_expert
-    return {
-        "router": ParamSpec((d, e), ("embed", None), "scaled_normal"),
+    d, e, f = cfg.d_model, m.n_held, m.d_ff_expert
+    spec = {
+        "router": ParamSpec((d, m.n_experts), ("embed", None), "scaled_normal"),
         "w_gate": ParamSpec((e, d, f), ("experts", "embed", "ffn"), "scaled_normal"),
         "w_up": ParamSpec((e, d, f), ("experts", "embed", "ffn"), "scaled_normal"),
         "w_down": ParamSpec((e, f, d), ("experts", "ffn", "embed"), "scaled_normal"),
     }
+    if m.n_shared_experts:
+        fs = m.n_shared_experts * f
+        spec["shared"] = {
+            "w_gate": ParamSpec((d, fs), ("embed", "ffn"), "scaled_normal"),
+            "w_up": ParamSpec((d, fs), ("embed", "ffn"), "scaled_normal"),
+            "w_down": ParamSpec((fs, d), ("ffn", "embed"), "scaled_normal"),
+        }
+    return spec
 
 
 def _capacity(n_tokens: int, top_k: int, n_experts: int, factor: float) -> int:
@@ -43,58 +61,95 @@ def _capacity(n_tokens: int, top_k: int, n_experts: int, factor: float) -> int:
     return max(c, top_k)
 
 
+def _grouped_experts(xf, w_gate, w_up, w_down, local_e, valid, top_w, k):
+    """Dropless: every (token, held expert) pair, sorted by expert, through
+    one grouped product per weight. Pairs routed elsewhere sort last,
+    outside every group, and add nothing."""
+    T, d = xf.shape
+    E_loc = w_gate.shape[0]
+    key = jnp.where(valid, local_e, E_loc)                         # (T*k,)
+    order = jnp.argsort(key, stable=True)
+    tok = order // k
+    sizes = jnp.bincount(key, length=E_loc + 1)[:E_loc].astype(jnp.int32)
+    xs = xf[tok]                                                   # (T*k, d)
+    g = lax.ragged_dot(xs, w_gate, sizes)
+    u = lax.ragged_dot(xs, w_up, sizes)
+    ys = lax.ragged_dot(jax.nn.silu(g) * u, w_down, sizes)         # (T*k, d)
+    held = valid[order]
+    w = jnp.where(held, top_w.reshape(-1)[order], 0.0)
+    ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
+    y = jnp.zeros((T, d), jnp.float32).at[tok].add(ys * w[:, None])
+    return y.astype(xf.dtype)
+
+
+def _capacity_experts(xf, w_gate, w_up, w_down, local_e, valid, top_w, k,
+                      capacity):
+    """Per-expert buffers of ``capacity`` slots; pairs past them drop."""
+    T, d = xf.shape
+    E_loc = w_gate.shape[0]
+    safe_e = jnp.where(valid, local_e, 0)
+    one_hot = jax.nn.one_hot(jnp.where(valid, local_e, E_loc),
+                             E_loc + 1, dtype=jnp.int32)           # (T*k, E_loc+1)
+    slot = (jnp.cumsum(one_hot, axis=0) - 1)[jnp.arange(T * k), safe_e]
+    keep = valid & (slot < capacity)
+    tok = jnp.arange(T * k) // k
+    scat_e = jnp.where(keep, safe_e, E_loc)                        # OOB -> drop
+    scat_s = jnp.where(keep, slot, 0)
+    buf = jnp.zeros((E_loc, capacity, d), xf.dtype)
+    buf = buf.at[scat_e, scat_s].add(xf[tok], mode="drop")
+
+    g = jnp.einsum("ecd,edf->ecf", buf, w_gate)
+    u = jnp.einsum("ecd,edf->ecf", buf, w_up)
+    out_buf = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, w_down)
+
+    # combine (gather + weighted sum over the k copies)
+    y_copies = out_buf[scat_e.clip(0, E_loc - 1), scat_s]          # (T*k, d)
+    w_copies = jnp.where(keep, top_w.reshape(-1), 0.0)
+    y = (y_copies * w_copies[:, None].astype(y_copies.dtype)
+         ).reshape(T, k, d).sum(axis=1)
+    return y.astype(xf.dtype)   # combine on the wire in bf16, not f32
+
+
 def _moe_core(
     xf: jax.Array,               # (T, d) local tokens
-    router_w: jax.Array,         # (d, E)
-    w_gate: jax.Array,           # (E_loc, d, f)
-    w_up: jax.Array,
-    w_down: jax.Array,           # (E_loc, f, d)
+    p: dict,                     # moe params; expert weights (E_loc, ...)
     *,
     cfg: ModelConfig,
-    e_first: jax.Array,          # scalar: first local expert id
+    e_first,                     # first held expert id (int or traced)
+    capacity_factor: Optional[float],  # None: dropless
     psum: Optional[Callable],    # combine fn over the expert axis, or None
     pmean_tokens: Optional[Callable],  # mean over batch shards for aux loss
 ) -> Tuple[jax.Array, jax.Array]:
     m = cfg.moe
     T, d = xf.shape
     E, k = m.n_experts, m.top_k
-    E_loc = w_gate.shape[0]
-    C = _capacity(T, k, E, m.capacity_factor)
 
-    gates = jax.nn.softmax(
-        jnp.einsum("td,de->te", xf, router_w,
-                   preferred_element_type=jnp.float32), axis=-1)  # (T, E)
-    top_w, top_i = lax.top_k(gates, k)                             # (T, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.router"):
+        gates = jax.nn.softmax(
+            jnp.einsum("td,de->te", xf, p["router"],
+                       preferred_element_type=jnp.float32), axis=-1)  # (T, E)
+        top_w, top_i = lax.top_k(gates, k)                         # (T, k)
+        if m.norm_topk_prob:
+            top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        if m.routed_scaling_factor != 1.0:
+            top_w = top_w * m.routed_scaling_factor
 
-    # ---- dispatch to local experts (scatter into capacity buffer) --------
-    flat_i = top_i.reshape(-1)                                     # (T*k,)
-    local_e = flat_i - e_first
-    valid = (local_e >= 0) & (local_e < E_loc)
-    safe_e = jnp.where(valid, local_e, 0)
-    one_hot = jax.nn.one_hot(jnp.where(valid, local_e, E_loc),
-                             E_loc + 1, dtype=jnp.int32)           # (T*k, E_loc+1)
-    slot = (jnp.cumsum(one_hot, axis=0) - 1)[jnp.arange(T * k), safe_e]
-    keep = valid & (slot < C)
-    tok = jnp.arange(T * k) // k
-    scat_e = jnp.where(keep, safe_e, E_loc)                        # OOB -> drop
-    scat_s = jnp.where(keep, slot, 0)
-    buf = jnp.zeros((E_loc, C, d), xf.dtype)
-    buf = buf.at[scat_e, scat_s].add(xf[tok], mode="drop")
+    with jax.named_scope("moe.experts"):
+        local_e = top_i.reshape(-1) - e_first                      # (T*k,)
+        valid = (local_e >= 0) & (local_e < p["w_gate"].shape[0])
+        experts = (p["w_gate"], p["w_up"], p["w_down"])
+        if capacity_factor is None:
+            y = _grouped_experts(xf, *experts, local_e, valid, top_w, k)
+        else:
+            C = _capacity(T, k, E, capacity_factor)
+            y = _capacity_experts(xf, *experts, local_e, valid, top_w, k, C)
+        if psum is not None:
+            y = psum(y)
 
-    # ---- expert FFN (SwiGLU) ---------------------------------------------
-    g = jnp.einsum("ecd,edf->ecf", buf, w_gate)
-    u = jnp.einsum("ecd,edf->ecf", buf, w_up)
-    out_buf = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, w_down)
-
-    # ---- combine (gather + weighted sum over the k copies) ---------------
-    y_copies = out_buf[scat_e.clip(0, E_loc - 1), scat_s]          # (T*k, d)
-    w_copies = jnp.where(keep, top_w.reshape(-1), 0.0)
-    y = (y_copies * w_copies[:, None].astype(y_copies.dtype)
-         ).reshape(T, k, d).sum(axis=1)
-    y = y.astype(xf.dtype)      # combine on the wire in bf16, not f32
-    if psum is not None:
-        y = psum(y)
+    if "shared" in p:            # every chip computes it alike: once
+        with jax.named_scope("moe.shared"):
+            sh = p["shared"]
+            y = y + swiglu(xf, sh["w_gate"], sh["w_up"], sh["w_down"])
 
     # ---- load-balance aux loss (Switch): E * sum_e f_e * p_e --------------
     assign = jax.nn.one_hot(top_i[:, 0], E, dtype=jnp.float32)     # top-1 assign
@@ -111,50 +166,54 @@ def moe_block(
     p: dict,                     # moe params
     cfg: ModelConfig,
     ctx: ShardCtx,
+    *,
+    dropless: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Apply the MoE FFN. Chooses EP (shard_map) vs local path from ctx."""
+    """Apply the MoE FFN. Chooses EP (shard_map) vs local path from ctx.
+    ``dropless=False`` (training) uses capacity buffers."""
     B, S, d = x.shape
     m = cfg.moe
-    E = m.n_experts
+    E = m.n_held
+    capacity_factor = None if dropless else m.capacity_factor
+
+    def local():
+        y, aux = _moe_core(x.reshape(B * S, d), p, cfg=cfg,
+                           e_first=jnp.int32(m.expert_first),
+                           capacity_factor=capacity_factor, psum=None,
+                           pmean_tokens=None)
+        return y.reshape(B, S, d), aux
 
     if not ctx.active or "model" not in ctx.mesh.axis_names:
-        xf = x.reshape(B * S, d)
-        y, aux = _moe_core(
-            xf, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            cfg=cfg, e_first=jnp.int32(0), psum=None, pmean_tokens=None)
-        return y.reshape(B, S, d), aux
+        return local()
 
     mesh = ctx.mesh
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     n_model = sizes["model"]
     if E % n_model != 0:
         # experts don't divide the model axis: fall back to replicated experts
-        xf = x.reshape(B * S, d)
-        y, aux = _moe_core(
-            xf, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            cfg=cfg, e_first=jnp.int32(0), psum=None, pmean_tokens=None)
-        return y.reshape(B, S, d), aux
+        return local()
 
     E_loc = E // n_model
     batch_axes = tuple(a for a in ("pod", "data") if a in sizes)
     x_spec = spec_for(("act_batch", None, None), x.shape, mesh, ctx.rules)
+    experts = P("model", None, None)
+    p_specs = jax.tree.map(lambda _: P(), p)
+    p_specs.update(w_gate=experts, w_up=experts, w_down=experts)
 
-    def inner(x_l, router_w, w_gate, w_up, w_down):
+    def inner(x_l, p_l):
         Bl, Sl, _ = x_l.shape
-        xf = x_l.reshape(Bl * Sl, d)
-        e_first = lax.axis_index("model") * E_loc
+        e_first = m.expert_first + lax.axis_index("model") * E_loc
         psum = lambda y: lax.psum(y, "model")
         pmean = (lambda a: lax.pmean(a, batch_axes)) if batch_axes else None
-        y, aux = _moe_core(xf, router_w, w_gate, w_up, w_down,
-                           cfg=cfg, e_first=e_first, psum=psum,
-                           pmean_tokens=pmean)
+        y, aux = _moe_core(x_l.reshape(Bl * Sl, d), p_l, cfg=cfg,
+                           e_first=e_first, capacity_factor=capacity_factor,
+                           psum=psum, pmean_tokens=pmean)
         return y.reshape(Bl, Sl, d), aux
 
     y, aux = shard_map(
         inner, mesh=mesh,
-        in_specs=(x_spec, P(None, None), P("model", None, None),
-                  P("model", None, None), P("model", None, None)),
+        in_specs=(x_spec, p_specs),
         out_specs=(x_spec, P()),
         check_vma=False,
-    )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
+    )(x, p)
     return y, aux

@@ -1,13 +1,15 @@
 """Decoder-only LM stack covering the dense, MoE, MLA, and VLM assigned
 architectures. One scan-over-layers implementation; per-arch behaviour is
-driven entirely by ``ModelConfig``.
+driven entirely by ``ModelConfig``. The layers form one or two homogeneous
+stacks (:func:`layer_stacks`): an MoE model's leading dense layers
+(``MoEConfig.first_k_dense``) scan apart from its expert layers.
 
 Shapes legend: B batch, S sequence, d d_model, H heads, KVH kv heads,
 hd head dim, V (padded) vocab, L layers, P vision-prefix length.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,13 +45,24 @@ def ffn_spec(cfg: ModelConfig) -> dict:
     }
 
 
-def block_spec(cfg: ModelConfig) -> dict:
+def layer_stacks(cfg: ModelConfig) -> List[Tuple[str, int, bool]]:
+    """``(params key, layers, is_moe)`` of each homogeneous stack, in
+    order: ``dense_blocks`` (an MoE model's leading dense layers), then
+    ``blocks``."""
+    if cfg.moe is None:
+        return [("blocks", cfg.n_layers, False)]
+    k = cfg.moe.first_k_dense
+    lead = [("dense_blocks", k, False)] if k else []
+    return lead + [("blocks", cfg.n_layers - k, True)]
+
+
+def block_spec(cfg: ModelConfig, is_moe: bool) -> dict:
     spec = {
         "ln1": ParamSpec((cfg.d_model,), ("embed",), "zeros"),
         "attn": attn.attn_spec(cfg),
         "ln2": ParamSpec((cfg.d_model,), ("embed",), "zeros"),
     }
-    if cfg.moe is not None:
+    if is_moe:
         spec["moe"] = moe_spec(cfg)
     else:
         spec["ffn"] = ffn_spec(cfg)
@@ -61,7 +74,8 @@ def model_spec(cfg: ModelConfig) -> dict:
     spec = {
         "embed": {"tok": ParamSpec((v, cfg.d_model), ("vocab", "embed"),
                                    "normal", 0.02)},
-        "blocks": stack(block_spec(cfg), cfg.n_layers),
+        **{key: stack(block_spec(cfg, is_moe), n)
+           for key, n, is_moe in layer_stacks(cfg)},
         "ln_f": ParamSpec((cfg.d_model,), ("embed",), "zeros"),
     }
     if not cfg.tie_embeddings:
@@ -120,6 +134,15 @@ def _embed_inputs(cfg: ModelConfig, params: dict, batch: Dict, dtype):
     return x, prefix
 
 
+def _ffn(cfg: ModelConfig, lp: dict, h: jax.Array, ctx: ShardCtx,
+         is_moe: bool, dropless: bool):
+    """The layer's feed-forward: (output, MoE aux loss)."""
+    if is_moe:
+        return moe_block(h, lp["moe"], cfg, ctx, dropless=dropless)
+    return (swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
+                   lp["ffn"]["w_down"]), jnp.float32(0.0))
+
+
 def forward_hidden(
     cfg: ModelConfig,
     params: dict,
@@ -130,10 +153,27 @@ def forward_hidden(
     *,
     collect_kv: bool = False,
     remat: Optional[str] = None,
-) -> Tuple[jax.Array, jax.Array, Optional[Tuple]]:
-    """Run the block stack. Returns (hidden, moe_aux_mean, kv_per_layer)."""
+    dropless: bool = False,
+) -> Tuple[jax.Array, jax.Array, Optional[Dict]]:
+    """Run the block stacks. Returns (hidden, moe_aux_mean, kv per stack:
+    ``{params key: kv stacked over its layers}``). ``dropless`` serves
+    every token routed to an expert; training's default drops past the
+    experts' capacity."""
     remat = knobs.remat if remat is None else remat
+    auxes, kvs = [], {}
+    for key, n, is_moe in layer_stacks(cfg):
+        x, aux, kv = _run_stack(cfg, params[key], x, positions, ctx, knobs,
+                                n, is_moe, collect_kv, remat, dropless)
+        if is_moe:               # the mean over the expert layers alone
+            auxes.append(aux)
+        kvs[key] = kv
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    aux = jnp.concatenate(auxes).mean() if auxes else jnp.float32(0.0)
+    return x, aux, kvs if collect_kv else None
 
+
+def _run_stack(cfg, blocks, x, positions, ctx, knobs, n, is_moe, collect_kv,
+               remat, dropless):
     def body(x, lp):
         x = ctx.constrain(x, ("act_batch", "act_seq", "act_embed"))
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -145,21 +185,15 @@ def forward_hidden(
             kv = None
         x = x + a
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            f, aux = moe_block(h, lp["moe"], cfg, ctx)
-        else:
-            f = swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
-                       lp["ffn"]["w_down"])
-            aux = jnp.float32(0.0)
+        f, aux = _ffn(cfg, lp, h, ctx, is_moe, dropless)
         x = x + f
         ys = (aux, kv) if collect_kv else (aux, None)
         return x, ys
 
     scan_body = _remat(body, remat) if not collect_kv else body
-    x, (aux, kv) = scan_or_loop(scan_body, x, params["blocks"],
-                                scan=knobs.scan_layers, length=cfg.n_layers)
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return x, aux.mean(), kv
+    x, (aux, kv) = scan_or_loop(scan_body, x, blocks,
+                                scan=knobs.scan_layers, length=n)
+    return x, aux, kv
 
 
 def loss_fn(
@@ -197,18 +231,21 @@ def loss_fn(
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> dict:
+    """Per stack (``cache["layers"][params key]``), each layer's cache
+    stacked over the stack's layers."""
     per_layer = attn.attn_cache_init(cfg, batch, max_seq, dtype)
-    stacked = jax.tree.map(
-        lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype), per_layer)
+    stacked = {key: jax.tree.map(
+        lambda x: jnp.zeros((n,) + x.shape, x.dtype), per_layer)
+        for key, n, _ in layer_stacks(cfg)}
     return {"layers": stacked,
             "pos": jnp.zeros((), jnp.int32),
             "lengths": jnp.zeros((batch,), jnp.int32)}
 
 
 def cache_axes(cfg: ModelConfig) -> dict:
-    layer = attn.attn_cache_axes(cfg)
-    return {"layers": jax.tree.map(lambda a: ("layers",) + a, layer,
-                                   is_leaf=lambda x: isinstance(x, tuple)),
+    layer = jax.tree.map(lambda a: ("layers",) + a, attn.attn_cache_axes(cfg),
+                         is_leaf=lambda x: isinstance(x, tuple))
+    return {"layers": {key: layer for key, _, _ in layer_stacks(cfg)},
             "pos": (),
             "lengths": ("cache_batch",)}
 
@@ -227,11 +264,12 @@ def prefill(
     B, S = x.shape[:2]
     positions = build_positions(cfg, B, S, prefix)
     hidden, _, kv = forward_hidden(cfg, params, x, positions, ctx, knobs,
-                                   collect_kv=True, remat="none")
+                                   collect_kv=True, remat="none",
+                                   dropless=True)
     logits = lm_logits(hidden[:, -1:], _head(cfg, params), cfg.vocab_size)
     max_seq = cache_len or S
-    layers = jax.vmap(lambda kv_l: attn.attn_cache_from_prefill(
-        cfg, kv_l, max_seq))(kv)
+    layers = {key: jax.vmap(lambda kv_l: attn.attn_cache_from_prefill(
+        cfg, kv_l, max_seq))(kv_s) for key, kv_s in kv.items()}
     cache = {"layers": layers,
              "pos": jnp.int32(S),
              "lengths": jnp.full((B,), S, jnp.int32)}
@@ -253,23 +291,24 @@ def decode_step(
     window = (cfg.recurrent.attention_window
               if (cfg.attention_kind == "local" and cfg.recurrent) else None)
 
-    def body(x, xs):
-        lp, cache_l = xs
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, new_cache_l = attn.attn_decode(cfg, lp["attn"], h, cache_l, pos,
-                                          lengths, ctx, window=window)
-        x = x + a
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            f, _ = moe_block(h, lp["moe"], cfg, ctx)
-        else:
-            f = swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
-                       lp["ffn"]["w_down"])
-        x = x + f
-        return x, new_cache_l
+    def stack_body(is_moe):
+        def body(x, xs):
+            lp, cache_l = xs
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            a, new_cache_l = attn.attn_decode(cfg, lp["attn"], h, cache_l,
+                                              pos, lengths, ctx, window=window)
+            x = x + a
+            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+            f, _ = _ffn(cfg, lp, h, ctx, is_moe, dropless=True)
+            x = x + f
+            return x, new_cache_l
+        return body
 
-    x, new_layers = scan_or_loop(body, x, (params["blocks"], cache["layers"]),
-                                 scan=knobs.scan_layers, length=cfg.n_layers)
+    new_layers = {}
+    for key, n, is_moe in layer_stacks(cfg):
+        x, new_layers[key] = scan_or_loop(
+            stack_body(is_moe), x, (params[key], cache["layers"][key]),
+            scan=knobs.scan_layers, length=n)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x, _head(cfg, params), cfg.vocab_size)
     new_cache = {"layers": new_layers, "pos": pos + 1, "lengths": lengths}

@@ -28,13 +28,17 @@ import numpy as np
 
 from ..compile_cache import enable_compile_cache
 from ..configs import get_config
-from ..core.spans import device_wait, span
+from ..core.spans import device_wait, span, stamp
+from ..core.tasks import FIRST_TOKEN, TOKENS
 from ..core.warming import ContainerRegistry, ContainerSpec
 
 JIT_PREFIX = "jit/"
 STEP_KINDS = ("generate", "prefill", "decode")
 _MIN_BUCKET = 16
 _DECODE_HORIZON = 32           # cache headroom compiled past the prompt
+# prefill's attention block: a 2,048-token bucket takes 4 x 4 blocks a
+# layer, where blocks of 64 took 1,024 steps of tiny products
+_ATTN_BLOCK = 512
 
 
 # ---------------------------------------------------------------------------
@@ -90,21 +94,22 @@ PARAMS_SEED = 0                 # weights are random, made from this seed
 def build_steps(model, bucket: int):
     """The jitted ``(prefill, decode)`` pair one environment serves a
     bucket with: prefill leaves ``_DECODE_HORIZON`` slots of cache past
-    the prompt for decode to fill."""
+    the prompt for decode to fill. Prefill attends in blocks of up to
+    ``_ATTN_BLOCK`` positions (a bucket below it is one block)."""
     import jax
 
     from ..models.knobs import RunKnobs
     from .serve_step import make_decode, make_prefill
 
-    knobs = RunKnobs(q_block=64, kv_block=64)
+    knobs = RunKnobs(q_block=_ATTN_BLOCK, kv_block=_ATTN_BLOCK)
     return (jax.jit(make_prefill(model, knobs=knobs,
                                  cache_len=bucket + _DECODE_HORIZON)),
             jax.jit(make_decode(model, knobs=knobs)))
 
 
 def _build_env(arch: str, step: str, bucket: int) -> Dict[str, Any]:
-    """Build one serving environment: init params and cast them once to
-    the compute dtype, jit-compile the step executables **eagerly** at the
+    """Build one serving environment: init params in the compute dtype,
+    jit-compile the step executables **eagerly** at the
     bucket shape — the build time the WarmCache records is the actual
     compile cost. Records the device the params live on, which every
     served result reports."""
@@ -116,9 +121,9 @@ def _build_env(arch: str, step: str, bucket: int) -> Dict[str, Any]:
     enable_compile_cache()
     cfg = get_config(arch)
     model = get_model(cfg)
-    # one jitted cast: eagerly, each distinct leaf shape compiles its own
-    params = jax.jit(model.compute_params)(
-        model.init(jax.random.PRNGKey(PARAMS_SEED)))
+    # init and cast in one jitted call: no whole fp32 tree is ever live
+    params = jax.jit(lambda key: model.compute_params(model.init(key)))(
+        jax.random.PRNGKey(PARAMS_SEED))
     prefill, decode = build_steps(model, bucket)
     probe = jnp.zeros((1, bucket), jnp.int32)
     out = prefill(params, {"tokens": probe})
@@ -184,26 +189,36 @@ def _fetch(x) -> np.ndarray:
 def serve_generate(data, env):
     """Batched generation inside the warm jit environment. Reports
     ``warm`` from an env-held uses counter, so clients can measure the
-    warm-hit rate without reaching into worker internals."""
+    warm-hit rate without reaching into worker internals. Stamps the
+    moment the first token is on the host and the tokens fetched.
+
+    Each decode step is enqueued before the host waits on the token
+    before it, so the device never idles while the host dispatches."""
     import jax
 
     from .sampler import sample
 
     uses, env["uses"] = env["uses"], env["uses"] + 1
     tokens = _put(data)
-    n_new = int(data.get("n_tokens", 4))
+    n_new = max(int(data.get("n_tokens", 4)), 1)
     with span("fabric.dispatch"):
         logits, cache = env["prefill"](env["params"], {"tokens": tokens})
         key = jax.random.PRNGKey(int(data.get("seed", 0)))
         tok = sample(logits, key, 0.0)
-    outs = [_fetch(tok)]
-    for _ in range(n_new - 1):
-        with span("fabric.dispatch"):
-            key, sub = jax.random.split(key)
-            logits, cache = env["decode"](env["params"], cache,
-                                          {"tokens": tok[:, None]})
-            tok = sample(logits, sub, 0.0)
+    outs = []
+    for i in range(n_new):
+        nxt = None
+        if i + 1 < n_new:
+            with span("fabric.dispatch"):
+                key, sub = jax.random.split(key)
+                logits, cache = env["decode"](env["params"], cache,
+                                              {"tokens": tok[:, None]})
+                nxt = sample(logits, sub, 0.0)
         outs.append(_fetch(tok))
+        if i == 0:
+            stamp(FIRST_TOKEN)
+        tok = nxt
+    stamp(TOKENS, float(len(outs)))
     return {"tokens": np.stack(outs, axis=1), "warm": uses > 0,
             **_served_by(env)}
 

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import FuncXClient, FuncXService
-from repro.core.tasks import DEVICE_WAIT, Task
+from repro.core.tasks import DEVICE_WAIT, FIRST_TOKEN, TOKENS, Task
 from repro.serve import fabric
 
 REPO = Path(__file__).resolve().parents[1]
@@ -76,6 +76,41 @@ def test_t_w_splits_into_host_work_and_device_wait(serve, step, data):
         assert bd["t_w_host"] >= 0 and bd["t_w_device"] > 0
         assert bd["t_w_host"] + bd["t_w_device"] == bd["t_w"]
         assert bd["t_w_device"] == pytest.approx(task.t[DEVICE_WAIT])
+
+
+def test_serve_generate_stamps_its_first_token_and_count(serve):
+    for n in (1, 4):
+        task = serve("generate", {"tokens": PROMPT, "n_tokens": n})
+        t = task.t
+        assert t[TOKENS] == n
+        assert t["worker_start"] < t[FIRST_TOKEN] <= t["worker_end"]
+        bd = task.latency_breakdown()
+        assert bd["t_w_first"] == t[FIRST_TOKEN] - t["worker_start"]
+        if n == 1:
+            assert math.isnan(bd["t_w_next"])
+        else:
+            assert bd["t_w_next"] == pytest.approx(
+                (t["worker_end"] - t[FIRST_TOKEN]) / (n - 1))
+            assert bd["t_w_first"] + (n - 1) * bd["t_w_next"] == \
+                pytest.approx(bd["t_w"])
+
+
+def test_tasks_that_generate_nothing_have_no_token_times(kept, serve):
+    svc, client = kept
+    task = serve("prefill", {"tokens": PROMPT})
+    eid, agent = svc.make_endpoint(client.token, "plain", n_managers=1,
+                                   workers_per_manager=1)
+    fid = client.register_function(_add)
+    try:
+        tid = client.run(fid, eid, data=[1, 2])
+        assert client.get_result(tid, timeout=30) == 3
+    finally:
+        agent.stop()
+    for task in (task, client.task(tid)):
+        assert FIRST_TOKEN not in task.t and TOKENS not in task.t
+        bd = task.latency_breakdown()
+        assert math.isnan(bd["t_w_first"]) and math.isnan(bd["t_w_next"])
+        assert bd["t_w"] > 0
 
 
 def test_a_plain_function_waits_on_no_device(kept):

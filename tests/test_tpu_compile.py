@@ -26,6 +26,7 @@ from repro.serve import fabric
 
 DEVICE_BYTES = 16 * 2**30           # one v5e chip's HBM
 QWEN = "qwen1.5-0.5b"
+DEEPSEEK = "deepseek-v2-lite@ep8"
 
 
 @pytest.fixture(scope="module")
@@ -76,13 +77,11 @@ def test_flash_attention_compiles_at_qwen_prefill_width(one_chip, seq, block):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.fixture(scope="module")
-def qwen_steps(one_chip):
-    """The published qwen1.5-0.5b prefill/decode pair, built as the fabric
-    builds it, with abstract params in the compute dtype the fabric keeps
-    them in, and inputs, on the described chip."""
-    model = get_model(get_config(QWEN))
-    bucket = 64
+def _fabric_steps(one_chip, arch, bucket):
+    """The prefill/decode pair of ``arch``, built as the fabric builds
+    it, with abstract params in the compute dtype the fabric keeps them
+    in, and inputs, on the described chip."""
+    model = get_model(get_config(arch))
     prefill, decode = fabric.build_steps(model, bucket)
     params = _on(one_chip, jax.eval_shape(model.compute_params,
                                           model.abstract_params()))
@@ -95,9 +94,23 @@ def qwen_steps(one_chip):
             "decode": (decode, (params, _on(one_chip, cache), step_batch))}
 
 
-@pytest.mark.parametrize("step", ["prefill", "decode"])
-def test_fabric_step_compiles_and_fits_one_chip(qwen_steps, step):
-    fn, args = qwen_steps[step]
+@pytest.fixture(scope="module")
+def fabric_steps(one_chip):
+    """The published qwen1.5-0.5b at a 64-token bucket, and one chip's
+    expert-parallel share of DeepSeek-V2-Lite at the 2,048-token bucket
+    its benchmark cell serves."""
+    return {"qwen": _fabric_steps(one_chip, QWEN, 64),
+            "deepseek": _fabric_steps(one_chip, DEEPSEEK, 2048)}
+
+
+@pytest.mark.parametrize("model,step", [
+    pytest.param("qwen", "prefill", id="prefill"),
+    pytest.param("qwen", "decode", id="decode"),
+    pytest.param("deepseek", "prefill", id="deepseek-v2-lite@ep8-prefill"),
+    pytest.param("deepseek", "decode", id="deepseek-v2-lite@ep8-decode"),
+])
+def test_fabric_step_compiles_and_fits_one_chip(fabric_steps, model, step):
+    fn, args = fabric_steps[model][step]
     mem = fn.lower(*args).compile().memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < DEVICE_BYTES, used
