@@ -7,7 +7,8 @@ execution) — `latency_breakdown()` reproduces Fig. 3 from any finished task.
 blocking reads, summed under ``DEVICE_WAIT`` by `spans.device_wait`) and the rest,
 its host work. A generating function also stamps its first token
 (``FIRST_TOKEN``) and its token count (``TOKENS``): time to first token and
-seconds per later token.
+seconds per later token; on an MoE model also the held experts its decode
+steps read per expert layer and step (``EXPERTS_READ``).
 """
 from __future__ import annotations
 
@@ -56,6 +57,9 @@ DEVICE_WAIT = "device_wait"
 # fetch returned (a time), and how many tokens it fetched (a count).
 FIRST_TOKEN = "first_token"
 TOKENS = "tokens"
+# A count of a generating MoE model: held experts read per expert layer and
+# decode step.
+EXPERTS_READ = "experts_read"
 
 
 @dataclass
@@ -105,6 +109,7 @@ class Task:
             # per later token
             "t_w_first": get("worker_start", FIRST_TOKEN),
             "t_w_next": t_w_next,
+            EXPERTS_READ: t.get(EXPERTS_READ, float("nan")),
             "t_r": get("worker_end", "result_stored"),
             "total": get("submit", "result_stored"),
         }

@@ -15,12 +15,24 @@ the result its own experts give, and adds the shared experts:
   by default): a chip's share of an expert-parallel deployment runs this
   path, and its partial result is what goes on to the next layer.
 
-Serving is dropless: the tokens routed to a held expert are sorted by
-expert and multiplied as one grouped product (``lax.ragged_dot``).
+Serving is dropless, and its route depends on the number of (token, held
+expert) pairs, ``T * top_k``, a static shape:
+
+- above ``LOOP_MAX_PAIRS`` (a prefill) the pairs are sorted by expert and
+  multiplied as one grouped product per weight (``lax.ragged_dot``);
+- at most ``LOOP_MAX_PAIRS`` (a decode step) a loop runs over the held
+  experts that got a pair, and only those experts' weights are read. Each
+  touched expert multiplies all the pairs (masked), ``T * top_k`` FLOP a
+  weight byte, which stays below the chip's ratio of FLOPs to HBM bytes,
+  so the loop is bound by the weights it reads. Given the layer-stacked
+  weights and a layer index, it slices ``(layer, expert)`` itself, so no
+  layer's whole expert slice is ever copied out.
+
 Training may instead ask for the capacity path (per-expert buffers of
 ``capacity_factor`` slots, overflow dropped).
 
-Returns the layer output plus the Switch-style load-balancing auxiliary loss.
+Returns the layer output, the Switch-style load-balancing auxiliary loss,
+and the number of held experts whose weights the layer read.
 """
 from __future__ import annotations
 
@@ -61,11 +73,32 @@ def _capacity(n_tokens: int, top_k: int, n_experts: int, factor: float) -> int:
     return max(c, top_k)
 
 
-def _grouped_experts(xf, w_gate, w_up, w_down, local_e, valid, top_w, k):
-    """Dropless: every (token, held expert) pair, sorted by expert, through
-    one grouped product per weight. Pairs routed elsewhere sort last,
-    outside every group, and add nothing."""
-    T, d = xf.shape
+# Pair counts up to this take the loop over touched experts: v5e's ratio of
+# FLOPs to HBM bytes (197e12 / 819e9) is ~240, so a product of this many
+# rows stays bound by the expert weights it reads.
+LOOP_MAX_PAIRS = 128
+
+
+def _layer_weight(w, layer, e):
+    """Expert ``e``'s matrix: ``w[e]`` of one layer's weights, or
+    ``w[layer, e]`` of the layer-stacked ones (``layer`` not None)."""
+    return w[e] if layer is None else w[layer, e]
+
+
+def _combine(ys, tok, w, T):
+    """Weight each pair's output by its gate and add it to its token, in
+    float32."""
+    y = jnp.zeros((T, ys.shape[-1]), jnp.float32)
+    return y.at[tok].add(ys * w[:, None])
+
+
+def _ragged_experts(xf, experts, layer, local_e, valid, top_w, k):
+    """Every (token, held expert) pair, sorted by expert, through one
+    grouped product per weight. Pairs routed elsewhere sort last, outside
+    every group, and add nothing. Reads every held expert."""
+    T = xf.shape[0]
+    w_gate, w_up, w_down = (w if layer is None else w[layer]
+                            for w in experts)
     E_loc = w_gate.shape[0]
     key = jnp.where(valid, local_e, E_loc)                         # (T*k,)
     order = jnp.argsort(key, stable=True)
@@ -78,8 +111,42 @@ def _grouped_experts(xf, w_gate, w_up, w_down, local_e, valid, top_w, k):
     held = valid[order]
     w = jnp.where(held, top_w.reshape(-1)[order], 0.0)
     ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
-    y = jnp.zeros((T, d), jnp.float32).at[tok].add(ys * w[:, None])
-    return y.astype(xf.dtype)
+    return _combine(ys, tok, w, T), jnp.int32(E_loc)
+
+
+def _looped_experts(xf, experts, layer, local_e, valid, top_w, k):
+    """A loop over the held experts that got a pair, in expert order; each
+    multiplies every pair with its three matrices, at the grouped
+    product's dtypes, and keeps the rows of its own pairs. Experts with no
+    pair are never read."""
+    T, d = xf.shape
+    E_loc = experts[0].shape[-3]
+    key = jnp.where(valid, local_e, E_loc)                         # (T*k,)
+    touched = jnp.bincount(key, length=E_loc + 1)[:E_loc] > 0
+    n = touched.sum(dtype=jnp.int32)
+    ids, = jnp.nonzero(touched, size=E_loc, fill_value=0)
+    tok = jnp.arange(T * k) // k
+    xs = xf[tok]                                                   # (T*k, d)
+
+    def one(i, ys):
+        e = ids[i]
+        w_gate, w_up, w_down = (_layer_weight(w, layer, e) for w in experts)
+        h = jax.nn.silu(xs @ w_gate) * (xs @ w_up)
+        out = (h @ w_down).astype(jnp.float32)
+        return jnp.where((key == e)[:, None], out, ys)
+
+    ys = lax.fori_loop(0, n, one, jnp.zeros((T * k, d), jnp.float32))
+    w = jnp.where(valid, top_w.reshape(-1), 0.0)
+    return _combine(ys, tok, w, T), n
+
+
+def _grouped_experts(xf, experts, layer, local_e, valid, top_w, k):
+    """Dropless: ``(y float32, held experts read)``, by the route the pair
+    count takes (module doc)."""
+    route = (_looped_experts if xf.shape[0] * k <= LOOP_MAX_PAIRS
+             else _ragged_experts)
+    y, n_read = route(xf, experts, layer, local_e, valid, top_w, k)
+    return y.astype(xf.dtype), n_read
 
 
 def _capacity_experts(xf, w_gate, w_up, w_down, local_e, valid, top_w, k,
@@ -112,14 +179,16 @@ def _capacity_experts(xf, w_gate, w_up, w_down, local_e, valid, top_w, k,
 
 def _moe_core(
     xf: jax.Array,               # (T, d) local tokens
-    p: dict,                     # moe params; expert weights (E_loc, ...)
+    p: dict,                     # moe params; expert weights (E_loc, ...),
+    #                              or (L, E_loc, ...) with ``layer``
     *,
     cfg: ModelConfig,
     e_first,                     # first held expert id (int or traced)
     capacity_factor: Optional[float],  # None: dropless
     psum: Optional[Callable],    # combine fn over the expert axis, or None
     pmean_tokens: Optional[Callable],  # mean over batch shards for aux loss
-) -> Tuple[jax.Array, jax.Array]:
+    layer=None,                  # index into layer-stacked expert weights
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     m = cfg.moe
     T, d = xf.shape
     E, k = m.n_experts, m.top_k
@@ -135,14 +204,17 @@ def _moe_core(
             top_w = top_w * m.routed_scaling_factor
 
     with jax.named_scope("moe.experts"):
-        local_e = top_i.reshape(-1) - e_first                      # (T*k,)
-        valid = (local_e >= 0) & (local_e < p["w_gate"].shape[0])
         experts = (p["w_gate"], p["w_up"], p["w_down"])
+        E_loc = experts[0].shape[-3]
+        local_e = top_i.reshape(-1) - e_first                      # (T*k,)
+        valid = (local_e >= 0) & (local_e < E_loc)
         if capacity_factor is None:
-            y = _grouped_experts(xf, *experts, local_e, valid, top_w, k)
+            y, n_read = _grouped_experts(xf, experts, layer, local_e, valid,
+                                         top_w, k)
         else:
             C = _capacity(T, k, E, capacity_factor)
             y = _capacity_experts(xf, *experts, local_e, valid, top_w, k, C)
+            n_read = jnp.int32(E_loc)
         if psum is not None:
             y = psum(y)
 
@@ -158,7 +230,7 @@ def _moe_core(
     aux = E * jnp.sum(f_e * p_e)
     if pmean_tokens is not None:
         aux = pmean_tokens(aux)
-    return y.astype(xf.dtype), aux
+    return y.astype(xf.dtype), aux, n_read
 
 
 def moe_block(
@@ -168,20 +240,23 @@ def moe_block(
     ctx: ShardCtx,
     *,
     dropless: bool = True,
-) -> Tuple[jax.Array, jax.Array]:
-    """Apply the MoE FFN. Chooses EP (shard_map) vs local path from ctx.
-    ``dropless=False`` (training) uses capacity buffers."""
+    layer=None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Apply the MoE FFN: ``(y, aux loss, held experts read)``. Chooses EP
+    (shard_map) vs local path from ctx. ``dropless=False`` (training) uses
+    capacity buffers. With ``layer``, the expert weights in ``p`` are the
+    whole stack's ``(L, E_loc, ...)``, read at that layer."""
     B, S, d = x.shape
     m = cfg.moe
     E = m.n_held
     capacity_factor = None if dropless else m.capacity_factor
 
     def local():
-        y, aux = _moe_core(x.reshape(B * S, d), p, cfg=cfg,
-                           e_first=jnp.int32(m.expert_first),
-                           capacity_factor=capacity_factor, psum=None,
-                           pmean_tokens=None)
-        return y.reshape(B, S, d), aux
+        y, aux, n_read = _moe_core(x.reshape(B * S, d), p, cfg=cfg,
+                                   e_first=jnp.int32(m.expert_first),
+                                   capacity_factor=capacity_factor, psum=None,
+                                   pmean_tokens=None, layer=layer)
+        return y.reshape(B, S, d), aux, n_read
 
     if not ctx.active or "model" not in ctx.mesh.axis_names:
         return local()
@@ -194,6 +269,8 @@ def moe_block(
         return local()
 
     E_loc = E // n_model
+    if layer is not None:        # shard one layer's experts over the mesh
+        p = dict(p, **{w: p[w][layer] for w in ("w_gate", "w_up", "w_down")})
     batch_axes = tuple(a for a in ("pod", "data") if a in sizes)
     x_spec = spec_for(("act_batch", None, None), x.shape, mesh, ctx.rules)
     experts = P("model", None, None)
@@ -205,15 +282,16 @@ def moe_block(
         e_first = m.expert_first + lax.axis_index("model") * E_loc
         psum = lambda y: lax.psum(y, "model")
         pmean = (lambda a: lax.pmean(a, batch_axes)) if batch_axes else None
-        y, aux = _moe_core(x_l.reshape(Bl * Sl, d), p_l, cfg=cfg,
-                           e_first=e_first, capacity_factor=capacity_factor,
-                           psum=psum, pmean_tokens=pmean)
-        return y.reshape(Bl, Sl, d), aux
+        y, aux, n_read = _moe_core(x_l.reshape(Bl * Sl, d), p_l, cfg=cfg,
+                                   e_first=e_first,
+                                   capacity_factor=capacity_factor,
+                                   psum=psum, pmean_tokens=pmean)
+        # every rank's reads, summed over the expert axis
+        return y.reshape(Bl, Sl, d), aux, lax.psum(n_read, "model")
 
-    y, aux = shard_map(
+    return shard_map(
         inner, mesh=mesh,
         in_specs=(x_spec, p_specs),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P(), P()),
         check_vma=False,
     )(x, p)
-    return y, aux

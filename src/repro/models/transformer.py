@@ -13,6 +13,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs import ModelConfig
 from ..sharding.rules import ShardCtx
@@ -138,7 +139,7 @@ def _ffn(cfg: ModelConfig, lp: dict, h: jax.Array, ctx: ShardCtx,
          is_moe: bool, dropless: bool):
     """The layer's feed-forward: (output, MoE aux loss)."""
     if is_moe:
-        return moe_block(h, lp["moe"], cfg, ctx, dropless=dropless)
+        return moe_block(h, lp["moe"], cfg, ctx, dropless=dropless)[:2]
     return (swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
                    lp["ffn"]["w_down"]), jnp.float32(0.0))
 
@@ -230,6 +231,17 @@ def loss_fn(
 # Serving: prefill + decode
 # ---------------------------------------------------------------------------
 
+# An MoE model's cache also counts the (layer, held expert) products its
+# decode steps ran: the weights they read, in units of one expert.
+EXPERTS_READ = "experts_read"
+
+
+def _with_counter(cfg: ModelConfig, cache: dict) -> dict:
+    if cfg.moe is not None:
+        cache[EXPERTS_READ] = jnp.zeros((), jnp.int32)
+    return cache
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> dict:
     """Per stack (``cache["layers"][params key]``), each layer's cache
     stacked over the stack's layers."""
@@ -237,17 +249,20 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> dict:
     stacked = {key: jax.tree.map(
         lambda x: jnp.zeros((n,) + x.shape, x.dtype), per_layer)
         for key, n, _ in layer_stacks(cfg)}
-    return {"layers": stacked,
-            "pos": jnp.zeros((), jnp.int32),
-            "lengths": jnp.zeros((batch,), jnp.int32)}
+    return _with_counter(cfg, {"layers": stacked,
+                               "pos": jnp.zeros((), jnp.int32),
+                               "lengths": jnp.zeros((batch,), jnp.int32)})
 
 
 def cache_axes(cfg: ModelConfig) -> dict:
     layer = jax.tree.map(lambda a: ("layers",) + a, attn.attn_cache_axes(cfg),
                          is_leaf=lambda x: isinstance(x, tuple))
-    return {"layers": {key: layer for key, _, _ in layer_stacks(cfg)},
+    axes = {"layers": {key: layer for key, _, _ in layer_stacks(cfg)},
             "pos": (),
             "lengths": ("cache_batch",)}
+    if cfg.moe is not None:
+        axes[EXPERTS_READ] = ()
+    return axes
 
 
 def prefill(
@@ -273,7 +288,7 @@ def prefill(
     cache = {"layers": layers,
              "pos": jnp.int32(S),
              "lengths": jnp.full((B,), S, jnp.int32)}
-    return logits[:, 0], cache
+    return logits[:, 0], _with_counter(cfg, cache)
 
 
 def decode_step(
@@ -284,32 +299,62 @@ def decode_step(
     ctx: ShardCtx = ShardCtx(),
     knobs: RunKnobs = DEFAULT_KNOBS,
 ) -> Tuple[jax.Array, dict]:
-    """One token for every sequence. batch = {"tokens": (B, 1)}."""
+    """One token for every sequence. batch = {"tokens": (B, 1)}.
+
+    An expert stack's routed weights stay out of the layer scan's inputs:
+    the body gets the layer's index and the MoE layer reads each expert it
+    needs at ``(layer, expert)`` of the stacked weights, so no layer's
+    whole slice of them is copied out."""
     dtype = jnp.dtype(cfg.dtype)
     x = embed_tokens(params["embed"]["tok"], batch["tokens"], dtype)  # (B,1,d)
     pos, lengths = cache["pos"], cache["lengths"] + 1
     window = (cfg.recurrent.attention_window
               if (cfg.attention_kind == "local" and cfg.recurrent) else None)
 
-    def stack_body(is_moe):
+    def block(x, lp, cache_l, ffn):
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        a, new_cache_l = attn.attn_decode(cfg, lp["attn"], h, cache_l,
+                                          pos, lengths, ctx, window=window)
+        x = x + a
+        f, n_read = ffn(rms_norm(x, lp["ln2"], cfg.norm_eps))
+        return x + f, (new_cache_l, n_read)
+
+    def dense_body(x, xs):
+        lp, cache_l = xs
+        f = lp["ffn"]
+        x, (new_cache_l, _) = block(x, lp, cache_l, lambda h: (
+            swiglu(h, f["w_gate"], f["w_up"], f["w_down"]), None))
+        return x, new_cache_l
+
+    def moe_body(experts):
         def body(x, xs):
-            lp, cache_l = xs
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, new_cache_l = attn.attn_decode(cfg, lp["attn"], h, cache_l,
-                                              pos, lengths, ctx, window=window)
-            x = x + a
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f, _ = _ffn(cfg, lp, h, ctx, is_moe, dropless=True)
-            x = x + f
-            return x, new_cache_l
+            lp, cache_l, layer = xs
+
+            def ffn(h):
+                y, _, n_read = moe_block(h, {**lp["moe"], **experts}, cfg,
+                                         ctx, layer=layer)
+                return y, n_read
+            return block(x, lp, cache_l, ffn)
         return body
 
-    new_layers = {}
+    new_layers, read = {}, None
     for key, n, is_moe in layer_stacks(cfg):
-        x, new_layers[key] = scan_or_loop(
-            stack_body(is_moe), x, (params[key], cache["layers"][key]),
+        if not is_moe:
+            x, new_layers[key] = scan_or_loop(
+                dense_body, x, (params[key], cache["layers"][key]),
+                scan=knobs.scan_layers, length=n)
+            continue
+        lp = params[key]
+        experts = {w: lp["moe"][w] for w in ("w_gate", "w_up", "w_down")}
+        lp = {**lp, "moe": {k: v for k, v in lp["moe"].items()
+                            if k not in experts}}
+        x, (new_layers[key], n_read) = scan_or_loop(
+            moe_body(experts), x, (lp, cache["layers"][key], np.arange(n)),
             scan=knobs.scan_layers, length=n)
+        read = n_read.sum()
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x, _head(cfg, params), cfg.vocab_size)
     new_cache = {"layers": new_layers, "pos": pos + 1, "lengths": lengths}
+    if read is not None:
+        new_cache[EXPERTS_READ] = cache[EXPERTS_READ] + read
     return logits[:, 0], new_cache

@@ -29,7 +29,7 @@ import numpy as np
 from ..compile_cache import enable_compile_cache
 from ..configs import get_config
 from ..core.spans import device_wait, span, stamp
-from ..core.tasks import FIRST_TOKEN, TOKENS
+from ..core.tasks import EXPERTS_READ, FIRST_TOKEN, TOKENS
 from ..core.warming import ContainerRegistry, ContainerSpec
 
 JIT_PREFIX = "jit/"
@@ -190,12 +190,16 @@ def serve_generate(data, env):
     """Batched generation inside the warm jit environment. Reports
     ``warm`` from an env-held uses counter, so clients can measure the
     warm-hit rate without reaching into worker internals. Stamps the
-    moment the first token is on the host and the tokens fetched.
+    moment the first token is on the host and the tokens fetched; for an
+    MoE model also the held experts its decode steps read, per expert
+    layer and step, from the cache's counter, fetched once after the last
+    token.
 
     Each decode step is enqueued before the host waits on the token
     before it, so the device never idles while the host dispatches."""
     import jax
 
+    from ..models import transformer
     from .sampler import sample
 
     uses, env["uses"] = env["uses"], env["uses"] + 1
@@ -219,6 +223,11 @@ def serve_generate(data, env):
             stamp(FIRST_TOKEN)
         tok = nxt
     stamp(TOKENS, float(len(outs)))
+    cfg = env["cfg"]
+    if n_new > 1 and transformer.EXPERTS_READ in cache:
+        steps = (n_new - 1) * (cfg.n_layers - cfg.moe.first_k_dense)
+        stamp(EXPERTS_READ,
+              float(_fetch(cache[transformer.EXPERTS_READ])) / steps)
     return {"tokens": np.stack(outs, axis=1), "warm": uses > 0,
             **_served_by(env)}
 

@@ -206,9 +206,9 @@ def test_a_routing_forced_onto_one_expert_drops_no_token():
     want = _layer_by_hand(x[0], jax.tree.map(np.asarray, p), cfg)
     gates = jax.nn.softmax(x[0] @ p["router"])
     assert (jnp.argmax(gates, -1) == 0).all()
-    y, _ = moe_block(x, p, cfg, ShardCtx())
+    y = moe_block(x, p, cfg, ShardCtx())[0]
     np.testing.assert_allclose(np.asarray(y[0]), want, rtol=0, atol=1e-4)
-    dropped, _ = moe_block(x, p, cfg, ShardCtx(), dropless=False)
+    dropped = moe_block(x, p, cfg, ShardCtx(), dropless=False)[0]
     lost = np.abs(np.asarray(dropped[0]) - want).max(-1) > 1e-3
     assert lost.sum() >= T - int(1.25 * T * cfg.moe.top_k / 8) - 1
 

@@ -12,6 +12,7 @@ Not covered while they fail to compile for the chip (ROADMAP 1.2):
 the process) and ``ssd_scan_kernel``.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,3 +115,18 @@ def test_fabric_step_compiles_and_fits_one_chip(fabric_steps, model, step):
     mem = fn.lower(*args).compile().memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < DEVICE_BYTES, used
+
+
+def test_deepseek_decode_reads_no_whole_layer_of_held_experts(fabric_steps):
+    """At one token the expert layer loops over the held experts it was
+    routed to, each read at ``(layer, expert)`` of the stacked weights: no
+    op outputs a layer's slice of all 8 held experts (the grouped
+    product's dense lowering did, and so does a loop that indexes the
+    scan's per-layer slice), and the temporaries stay small."""
+    fn, args = fabric_steps["deepseek"]["decode"]
+    compiled = fn.lower(*args).compile()
+    whole = re.findall(r"bf16\[8,(?:2048,1408|1408,2048)\]",
+                       compiled.as_text())
+    assert not whole, len(whole)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 8 * 2**20, temp
