@@ -3,9 +3,8 @@
     PYTHONPATH=src python -m benchmarks.run [--only fig3_latency,...] [--full]
 
 Prints ``name,value,derived`` CSV (value µs unless the name states
-otherwise). Roofline terms for §Roofline come from the compiled dry-run
-(``python -m repro.launch.dryrun``), not from here — this harness measures
-the FaaS system itself, which runs for real on CPU.
+otherwise). This harness measures the FaaS system itself, which runs for
+real on CPU.
 """
 from __future__ import annotations
 

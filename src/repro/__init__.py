@@ -5,9 +5,9 @@ Layers (see DESIGN.md):
   - ``repro.core``       the funcX contribution: federated FaaS runtime
   - ``repro.data``       intra/inter-endpoint data management
   - ``repro.models``     the 10 assigned architectures (pure JAX)
-  - ``repro.kernels``    Pallas TPU kernels for compute hot-spots
+  - ``repro.kernels``    Pallas TPU kernels (no model path calls them)
   - ``repro.train``/``repro.serve``  substrate for the two step kinds
-  - ``repro.launch``     meshes, dry-run, drivers
+  - ``repro.launch``     train and serve drivers
 """
 
 __version__ = "1.0.0"

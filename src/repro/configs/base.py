@@ -7,7 +7,6 @@ DESIGN.md §2).
 from __future__ import annotations
 
 import dataclasses
-import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -178,30 +177,6 @@ class ShapeConfig:
     @property
     def is_decode(self) -> bool:
         return self.kind == "decode"
-
-
-@dataclass(frozen=True)
-class MeshConfig:
-    shape: Tuple[int, ...]
-    axes: Tuple[str, ...]
-
-    @property
-    def n_devices(self) -> int:
-        return math.prod(self.shape)
-
-    @property
-    def batch_axes(self) -> Tuple[str, ...]:
-        return tuple(a for a in self.axes if a in ("pod", "data"))
-
-
-@dataclass(frozen=True)
-class ShardingConfig:
-    """Sharding policy knobs — the hillclimb surface for §Perf."""
-    policy: str = "fsdp"            # "dp" | "fsdp" | "tp" | "fsdp_tp"
-    shard_sequence: bool = False    # sequence parallelism for batch-1 decode
-    remat: str = "full"             # "none" | "dots" | "full"
-    scan_layers: bool = True
-    repeat_kv_for_tp: bool = False  # replicate kv heads so TP divides evenly
 
 
 @dataclass(frozen=True)

@@ -21,7 +21,7 @@ advertised warm state is read and mutated through one
 Beyond-paper routers:
   - ``CostAwareRouter`` scores managers by expected completion time
     (queue wait + cold-start cost when no warm copy), using the
-    endpoint's measured build times — a dry-run-informed scheduler.
+    endpoint's measured build times.
   - ``LocalityAwareRouter`` breaks warm ties toward managers whose local
     store already holds the task's input refs.
 

@@ -2,8 +2,7 @@
 
 ``get_model(cfg)`` returns a :class:`Model` facade dispatching to the family
 implementation (transformer / ssm / rglru / encdec). The facade is what the
-FaaS layer registers as *functions* (train_step / prefill / decode_step) and
-what the dry-run lowers.
+FaaS layer registers as *functions* (train_step / prefill / decode_step).
 
 ``input_specs(cfg, shape, kind)`` builds ShapeDtypeStruct stand-ins for every
 model input — weak-type-correct, shardable, no device allocation.
@@ -111,7 +110,7 @@ def get_model(cfg: ModelConfig) -> Model:
 
 
 # ---------------------------------------------------------------------------
-# Input specs (ShapeDtypeStruct stand-ins for the dry-run & FaaS signatures)
+# Input specs (ShapeDtypeStruct stand-ins for the FaaS signatures)
 # ---------------------------------------------------------------------------
 
 def input_specs(cfg: ModelConfig, shape: ShapeConfig,

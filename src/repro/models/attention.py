@@ -123,17 +123,8 @@ def attn_full(
     w = window if window is not None else (
         cfg.recurrent.attention_window
         if (cfg.attention_kind == "local" and cfg.recurrent) else None)
-    if knobs.attn_stub:
-        # analysis stub: keep qkv/out projections, skip the attention core
-        G = cfg.n_heads // cfg.n_kv_heads
-        out = jnp.repeat(v, G, axis=2)
-    elif knobs.use_kernels:
-        from ..kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=causal, window=w)
-    else:
-        out = chunked_attention(q, k, v, causal=causal, window=w,
-                                q_block=knobs.q_block, kv_block=knobs.kv_block,
-                                unroll=not knobs.scan_layers)
+    out = chunked_attention(q, k, v, causal=causal, window=w,
+                            q_block=knobs.q_block, kv_block=knobs.kv_block)
     B, S = h.shape[:2]
     y = jnp.einsum("bsk,kd->bsd", out.reshape(B, S, -1), p["wo"])
     if return_kv:
@@ -321,8 +312,7 @@ def mla_full(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array,
     v = ctx.constrain(v, ("act_batch", "act_seq", "act_heads", None))
     out = chunked_attention(q, k, v.astype(q.dtype), causal=True,
                             q_block=knobs.q_block, kv_block=knobs.kv_block,
-                            softmax_scale=mla_softmax_scale(cfg),
-                            unroll=not knobs.scan_layers)
+                            softmax_scale=mla_softmax_scale(cfg))
     y = jnp.einsum("bsk,kd->bsd", out.reshape(B, S, -1), p["wo"])
     if return_kv:
         return y, (c_kv, k_pe)

@@ -3,9 +3,9 @@
 
 The chunked attention here is the *default execution path* of the framework
 (pure JAX, flash-style online softmax, compiles to bounded-memory HLO on any
-backend). The Pallas kernels in ``repro.kernels`` are the TPU-target
-implementations of the same contract and are validated against
-``repro.kernels.ref`` oracles; select them with ``use_kernels=True``.
+backend) and the only one the models take. The Pallas kernels in
+``repro.kernels`` implement the same contract and are validated against it
+directly; no model path calls them.
 """
 from __future__ import annotations
 
@@ -102,14 +102,9 @@ def chunked_attention(
     q_block: int = 1024,
     kv_block: int = 1024,
     softmax_scale: Optional[float] = None,
-    unroll: bool = False,
 ) -> jax.Array:
     """Flash-style attention: outer scan over q blocks, inner scan over kv
-    blocks with online softmax. Peak temp ~ (B, KVH, G, q_block, kv_block).
-
-    ``unroll=True`` replaces the scans with python loops — used ONLY by the
-    roofline analysis lowerings (XLA cost_analysis counts while bodies once,
-    which would undercount attention by n_q·n_k)."""
+    blocks with online softmax. Peak temp ~ (B, KVH, G, q_block, kv_block)."""
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
     Dv = v.shape[-1]
@@ -163,21 +158,12 @@ def chunked_attention(
         m0 = jnp.full((B, KVH, G, q_block), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KVH, G, q_block), jnp.float32)
         a0 = jnp.zeros((B, q_block, KVH, G, Dv), jnp.float32)
-        carry = (m0, l0, a0)
-        if unroll:
-            for ki in range(n_k):
-                carry, _ = kv_step(carry, ki)
-            m, l, acc = carry
-        else:
-            (m, l, acc), _ = lax.scan(kv_step, carry, jnp.arange(n_k))
+        (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), jnp.arange(n_k))
         l = jnp.moveaxis(l, -1, 1)[..., None]                # (B,qb,KVH,G,1)
         out = acc / jnp.maximum(l, 1e-30)
         return None, out.astype(q.dtype)
 
-    if unroll:
-        blocks = jnp.stack([q_step(None, qi)[1] for qi in range(n_q)])
-    else:
-        _, blocks = lax.scan(q_step, None, jnp.arange(n_q))  # (n_q,B,qb,…)
+    _, blocks = lax.scan(q_step, None, jnp.arange(n_q))      # (n_q,B,qb,…)
     out = jnp.moveaxis(blocks, 0, 1).reshape(B, Sq_p, H, Dv)
     return out[:, :Sq]
 
@@ -251,44 +237,3 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
     mask = mask.astype(nll.dtype)
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
-
-def chunked_cross_entropy(x: jax.Array, head: jax.Array, labels: jax.Array,
-                          vocab_size: int, mask: Optional[jax.Array] = None,
-                          z_loss: float = 0.0, chunk: int = 512,
-                          unroll: bool = False) -> jax.Array:
-    """Beyond-paper memory optimization: compute logits + CE per sequence
-    chunk inside a scan so the (B, S, V) logits tensor is never materialized.
-    Used when the sharding config enables ``chunked_loss``."""
-    B, S, d = x.shape
-    pad = (-S) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, pad)))
-        m = jnp.pad(mask if mask is not None else jnp.ones((B, S), jnp.float32),
-                    ((0, 0), (0, pad)))
-    else:
-        m = mask if mask is not None else jnp.ones((B, S), jnp.float32)
-    n = (S + pad) // chunk
-    xr = x.reshape(B, n, chunk, d)
-    lr = labels.reshape(B, n, chunk)
-    mr = m.reshape(B, n, chunk)
-
-    def step(carry, i):
-        tot, cnt = carry
-        logits = lm_logits(xr[:, i], head, vocab_size)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lr[:, i][..., None], axis=-1)[..., 0]
-        nll = lse - gold
-        if z_loss:
-            nll = nll + z_loss * jnp.square(lse)
-        mi = mr[:, i].astype(nll.dtype)
-        return (tot + (nll * mi).sum(), cnt + mi.sum()), None
-
-    carry = (jnp.float32(0.0), jnp.float32(0.0))
-    if unroll:
-        for i in range(n):
-            carry, _ = step(carry, i)
-        tot, cnt = carry
-    else:
-        (tot, cnt), _ = lax.scan(step, carry, jnp.arange(n))
-    return tot / jnp.maximum(cnt, 1.0)

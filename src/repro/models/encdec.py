@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..configs import ModelConfig
@@ -20,7 +21,6 @@ from ..sharding.rules import ShardCtx
 from . import attention as attn
 from .common import (
     chunked_attention,
-    chunked_cross_entropy,
     cross_entropy,
     decode_attention,
     embed_tokens,
@@ -29,7 +29,7 @@ from .common import (
     swiglu,
 )
 from .knobs import DEFAULT_KNOBS
-from .params import ParamSpec, scan_or_loop, stack
+from .params import ParamSpec, stack
 from .transformer import _remat, ffn_spec
 
 
@@ -101,8 +101,7 @@ def _cross_full(cfg, p, h, mem, ctx, knobs, collect=False):
     q = jnp.einsum("bsd,dk->bsk", h, p["wq"]).reshape(B, S, H, hd)
     k, v = _cross_kv(cfg, p, mem)
     out = chunked_attention(q, k, v, causal=False,
-                            q_block=knobs.q_block, kv_block=knobs.kv_block,
-                            unroll=not knobs.scan_layers)
+                            q_block=knobs.q_block, kv_block=knobs.kv_block)
     y = jnp.einsum("bsk,kd->bsd", out.reshape(B, S, -1), p["wo"])
     if collect:
         return y, (k, v)
@@ -138,9 +137,7 @@ def encode(cfg, params, frames, ctx, knobs):
                        lp["ffn"]["w_down"])
         return x, None
 
-    x, _ = scan_or_loop(_remat(body, knobs.remat), x, params["enc_blocks"],
-                        scan=knobs.scan_layers,
-                        length=cfg.encdec.n_encoder_layers)
+    x, _ = jax.lax.scan(_remat(body, knobs.remat), x, params["enc_blocks"])
     return rms_norm(x, params["enc_ln_f"], cfg.norm_eps)
 
 
@@ -170,8 +167,7 @@ def decode_stack(cfg, params, x, mem, positions, ctx, knobs,
         return x, (kv, xkv)
 
     body_fn = body if collect else _remat(body, knobs.remat)
-    x, states = scan_or_loop(body_fn, x, params["dec_blocks"],
-                             scan=knobs.scan_layers, length=cfg.n_layers)
+    x, states = jax.lax.scan(body_fn, x, params["dec_blocks"])
     return rms_norm(x, params["ln_f"], cfg.norm_eps), states
 
 
@@ -187,14 +183,8 @@ def loss_fn(cfg, params, batch, ctx=ShardCtx(), knobs=DEFAULT_KNOBS,
     B, St = batch["tokens"].shape
     positions = jnp.broadcast_to(jnp.arange(St, dtype=jnp.int32)[None], (B, St))
     x, _ = decode_stack(cfg, params, x, mem, positions, ctx, knobs)
-    if knobs.chunked_loss:
-        ce = chunked_cross_entropy(x, params["lm_head"], batch["labels"],
-                                   cfg.vocab_size, batch.get("mask"), z_loss,
-                                   knobs.loss_chunk,
-                                   unroll=not knobs.scan_layers)
-    else:
-        logits = lm_logits(x, params["lm_head"], cfg.vocab_size)
-        ce = cross_entropy(logits, batch["labels"], batch.get("mask"), z_loss)
+    logits = lm_logits(x, params["lm_head"], cfg.vocab_size)
+    ce = cross_entropy(logits, batch["labels"], batch.get("mask"), z_loss)
     return ce, {"ce": ce, "moe_aux": jnp.float32(0.0)}
 
 
@@ -270,9 +260,8 @@ def decode_step(cfg, params, cache, batch, ctx=ShardCtx(),
                        "xk": cache_l["xk"], "xv": cache_l["xv"]}
         return x, new_cache_l
 
-    x, new_layers = scan_or_loop(body, x,
-                                 (params["dec_blocks"], cache["layers"]),
-                                 scan=knobs.scan_layers, length=cfg.n_layers)
+    x, new_layers = jax.lax.scan(body, x,
+                                 (params["dec_blocks"], cache["layers"]))
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x, params["lm_head"], cfg.vocab_size)
     return logits[:, 0], {"layers": new_layers, "pos": pos + 1,

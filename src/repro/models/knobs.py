@@ -1,7 +1,6 @@
-"""Runtime knobs orthogonal to the architecture config — the execution-path
-and performance surface (kernel selection, block sizes, remat, loss chunking).
-Part of the *compile signature* (funcX container type) together with the
-ModelConfig, ShapeConfig, and mesh."""
+"""Runtime knobs orthogonal to the architecture config: the attention block
+sizes and the rematerialisation policy. Part of the *compile signature*
+(funcX container type) together with the ModelConfig, ShapeConfig, and mesh."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -9,23 +8,9 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class RunKnobs:
-    use_kernels: bool = False    # Pallas kernels (TPU target) vs chunked-jnp
     q_block: int = 1024
     kv_block: int = 1024
     remat: str = "full"          # "none" | "dots" | "full"
-    chunked_loss: bool = False   # never materialize (B, S, V) logits
-    loss_chunk: int = 512
-    causal_skip: bool = False    # skip fully-masked kv blocks in causal attn
-    # scan over layers (production) vs unrolled python loop. The unrolled
-    # form exists because XLA cost_analysis counts while bodies ONCE —
-    # roofline analysis lowers unrolled 1-/2-period variants and
-    # extrapolates exact per-layer costs (see launch/dryrun.py).
-    scan_layers: bool = True
-    # ANALYSIS-ONLY: replace the attention core (scores/softmax/context)
-    # with a shape-preserving stub so its exact byte/flop contribution can
-    # be isolated by differencing two lowerings — the Pallas flash kernel's
-    # cost model is then substituted (§Perf "kernel-adjusted" iterations).
-    attn_stub: bool = False
 
 
 DEFAULT_KNOBS = RunKnobs()

@@ -6,8 +6,8 @@ Every model declares its parameters once, as a nested dict of :class:`ParamSpec`
 - ``init_params``     — materialized arrays (seeded per path)
 - ``logical_axes``    — same-structure pytree of logical-axis tuples, consumed
                         by ``repro.sharding.rules`` to build NamedShardings
-- ``abstract_params`` — ShapeDtypeStructs for dry-run lowering (no allocation)
-- ``count_params``    — exact parameter counts (used for roofline 6·N·D)
+- ``abstract_params`` — ShapeDtypeStructs for lowering without allocation
+- ``count_params``    — exact parameter counts
 
 Stacked (scanned) layers are expressed by :func:`stack` which prepends a
 ``"layers"`` axis (never sharded).
@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import math
 import zlib
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,26 +86,6 @@ def abstract_params(spec_tree: Any, dtype=jnp.float32) -> Any:
 def count_params(spec_tree: Any) -> int:
     return sum(int(np.prod(s.shape))
                for s in jax.tree.leaves(spec_tree, is_leaf=_is_spec))
-
-
-def scan_or_loop(body: Callable, carry: Any, xs: Any, *, scan: bool,
-                 length: int):
-    """``lax.scan(body, carry, xs)`` or an unrolled python loop with
-    identical semantics (used by the roofline analysis lowerings — XLA's
-    cost_analysis counts while bodies once, so unrolled variants give exact
-    per-layer costs)."""
-    if scan:
-        return jax.lax.scan(body, carry, xs)
-    ys = []
-    for i in range(length):
-        xi = jax.tree.map(lambda a: a[i], xs)
-        carry, y = body(carry, xi)
-        ys.append(y)
-    if ys:
-        stacked = jax.tree.map(lambda *z: jnp.stack(z), *ys)
-    else:
-        stacked = None
-    return carry, stacked
 
 
 def cast_floats(tree: Any, dtype) -> Any:

@@ -23,14 +23,13 @@ from . import attention as attn
 from .common import (
     NEG_INF,
     apply_rope,
-    chunked_cross_entropy,
     cross_entropy,
     embed_tokens,
     lm_logits,
     rms_norm,
 )
 from .knobs import DEFAULT_KNOBS
-from .params import ParamSpec, scan_or_loop, stack
+from .params import ParamSpec, stack
 from .ssm import causal_conv, conv_step
 
 RG_C = 8.0          # RG-LRU decay sharpness constant (Griffin §2.4)
@@ -131,18 +130,15 @@ def rglru_gates(p: dict, x: jax.Array):
     return log_a, bx
 
 
-def rglru_full(p: dict, x: jax.Array, use_kernel: bool = False):
+def rglru_full(p: dict, x: jax.Array):
     """Linear recurrence over the sequence. Returns (h (B,S,lru), h_last)."""
     log_a, bx = rglru_gates(p, x)
-    if use_kernel:
-        from ..kernels import ops as kops
-        h = kops.rglru(jnp.exp(log_a), bx)
-    else:
-        def combine(e1, e2):
-            a1, b1 = e1
-            a2, b2 = e2
-            return a1 * a2, a2 * b1 + b2
-        _, h = lax.associative_scan(combine, (jnp.exp(log_a), bx), axis=1)
+
+    def combine(e1, e2):
+        a1, b1 = e1
+        a2, b2 = e2
+        return a1 * a2, a2 * b1 + b2
+    _, h = lax.associative_scan(combine, (jnp.exp(log_a), bx), axis=1)
     return h.astype(x.dtype), h[:, -1]
 
 
@@ -163,14 +159,14 @@ def _gelu_ffn(p: dict, x: jax.Array) -> jax.Array:
     return jnp.einsum("...f,fd->...d", jax.nn.gelu(g) * u, p["w_down"])
 
 
-def rec_block_full(cfg, p, x_res, ctx, knobs, collect=False):
+def rec_block_full(cfg, p, x_res, ctx, collect=False):
     r = cfg.recurrent
     h = rms_norm(x_res, p["ln1"], cfg.norm_eps)
     gate = jax.nn.gelu(jnp.einsum("bsd,dl->bsl", h, p["w_gate"]))
     xr = jnp.einsum("bsd,dl->bsl", h, p["w_x"])
     conv_in = xr
     xr = causal_conv(xr, p["conv"])
-    hr, h_last = rglru_full(p, xr, use_kernel=knobs.use_kernels)
+    hr, h_last = rglru_full(p, xr)
     y = jnp.einsum("bsl,ld->bsd", hr * gate, p["w_out"])
     x_res = x_res + y
     h2 = rms_norm(x_res, p["ln2"], cfg.norm_eps)
@@ -263,7 +259,7 @@ def _stack_forward(cfg, params, x, positions, ctx, knobs, collect=False):
     if head:
         for i in range(head):
             x, st = rec_block_full(cfg, _tree_idx(params["head_rec"], i),
-                                   x, ctx, knobs, collect)
+                                   x, ctx, collect)
             head_states.append(st)
 
     sb_states = None
@@ -275,7 +271,7 @@ def _stack_forward(cfg, params, x, positions, ctx, knobs, collect=False):
             r_sts = []
             for i in range(2):
                 x, r_st = rec_block_full(cfg, _tree_idx(lp["rec"], i), x,
-                                         ctx, knobs, collect)
+                                         ctx, collect)
                 r_sts.append(r_st)
             if collect:
                 r_stack = jax.tree.map(lambda *z: jnp.stack(z), *r_sts)
@@ -284,8 +280,7 @@ def _stack_forward(cfg, params, x, positions, ctx, knobs, collect=False):
 
         sb_params = {"attn_blk": params["sb"]["attn"], "rec": params["sb"]["rec"]}
         body_fn = body if collect else _remat(body, knobs.remat)
-        x, sb_states = scan_or_loop(body_fn, x, sb_params,
-                                    scan=knobs.scan_layers, length=n_sb)
+        x, sb_states = lax.scan(body_fn, x, sb_params)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     return x, head_states, sb_states
 
@@ -305,13 +300,8 @@ def loss_fn(cfg, params, batch, ctx=ShardCtx(), knobs=DEFAULT_KNOBS,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     x, _, _ = _stack_forward(cfg, params, x, positions, ctx, knobs)
     head = _head_w(cfg, params)
-    if knobs.chunked_loss:
-        ce = chunked_cross_entropy(x, head, batch["labels"], cfg.vocab_size,
-                                   batch.get("mask"), z_loss, knobs.loss_chunk,
-                                   unroll=not knobs.scan_layers)
-    else:
-        logits = lm_logits(x, head, cfg.vocab_size)
-        ce = cross_entropy(logits, batch["labels"], batch.get("mask"), z_loss)
+    logits = lm_logits(x, head, cfg.vocab_size)
+    ce = cross_entropy(logits, batch["labels"], batch.get("mask"), z_loss)
     return ce, {"ce": ce, "moe_aux": jnp.float32(0.0)}
 
 
@@ -419,9 +409,8 @@ def decode_step(cfg, params, cache, batch, ctx=ShardCtx(),
             return x, (a_st, r_stack)
 
         sb_params = {"attn_blk": params["sb"]["attn"], "rec": params["sb"]["rec"]}
-        x, (a_st, r_st) = scan_or_loop(
-            body, x, (sb_params, cache["sb"]["attn"], cache["sb"]["rec"]),
-            scan=knobs.scan_layers, length=n_sb)
+        x, (a_st, r_st) = lax.scan(
+            body, x, (sb_params, cache["sb"]["attn"], cache["sb"]["rec"]))
         new_cache["sb"] = {"attn": a_st, "rec": r_st}
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x, _head_w(cfg, params), cfg.vocab_size)

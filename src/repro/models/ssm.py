@@ -18,14 +18,13 @@ from jax import lax
 from ..configs import ModelConfig
 from ..sharding.rules import ShardCtx
 from .common import (
-    chunked_cross_entropy,
     cross_entropy,
     embed_tokens,
     lm_logits,
     rms_norm,
 )
-from .knobs import DEFAULT_KNOBS, RunKnobs
-from .params import ParamSpec, scan_or_loop, stack
+from .knobs import DEFAULT_KNOBS
+from .params import ParamSpec, stack
 
 
 # ---------------------------------------------------------------------------
@@ -106,7 +105,6 @@ def ssd_scan(
     Cm: jax.Array,       # (B, S, H, N)
     chunk: int,
     h0: Optional[jax.Array] = None,
-    unroll: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD. Returns (y (B,S,H,P), final state (B,H,P,N))."""
     Bsz, S, H, P = x.shape
@@ -149,14 +147,7 @@ def ssd_scan(
         h_new = chunk_decay[:, :, None, None] * h_prev + dh
         return h_new, (y_intra + y_inter).astype(x.dtype)
 
-    if unroll:
-        h_final, ys_l = h0, []
-        for ci in range(nc):
-            h_final, y_c = step(h_final, ci)
-            ys_l.append(y_c)
-        ys = jnp.stack(ys_l)
-    else:
-        h_final, ys = lax.scan(step, h0, jnp.arange(nc))     # ys (nc,B,q,H,P)
+    h_final, ys = lax.scan(step, h0, jnp.arange(nc))         # ys (nc,B,q,H,P)
     y = jnp.moveaxis(ys, 0, 1).reshape(Bsz, S + pad, H, P)[:, :S]
     return y, h_final
 
@@ -205,7 +196,7 @@ def _gates(cfg, p, x, Bm, Cm, dt):
 
 
 def block_full(cfg: ModelConfig, p: dict, x_res: jax.Array, ctx: ShardCtx,
-               knobs: RunKnobs, collect_state: bool = False):
+               collect_state: bool = False):
     s, d_in, H, P, gn = _dims(cfg)
     h = rms_norm(x_res, p["ln"], cfg.norm_eps)
     z, x, Bm, Cm, dt = _proj_inputs(cfg, p, h)
@@ -214,12 +205,7 @@ def block_full(cfg: ModelConfig, p: dict, x_res: jax.Array, ctx: ShardCtx,
     Bm = causal_conv(Bm, p["conv_B"])
     Cm = causal_conv(Cm, p["conv_C"])
     xh, x_dt, a, Bh, Ch = _gates(cfg, p, x, Bm, Cm, dt)
-    if knobs.use_kernels:
-        from ..kernels import ops as kops
-        y, h_final = kops.ssd(x_dt, a, Bh, Ch, chunk=s.chunk_size)
-    else:
-        y, h_final = ssd_scan(x_dt, a, Bh, Ch, chunk=s.chunk_size,
-                              unroll=not knobs.scan_layers)
+    y, h_final = ssd_scan(x_dt, a, Bh, Ch, chunk=s.chunk_size)
     y = y + p["D"][None, None, :, None] * xh                     # skip
     Bsz, S = x_res.shape[:2]
     y = y.reshape(Bsz, S, d_in)
@@ -263,21 +249,14 @@ def loss_fn(cfg, params, batch, ctx=ShardCtx(), knobs=DEFAULT_KNOBS,
 
     def body(x, lp):
         x = ctx.constrain(x, ("act_batch", "act_seq", "act_embed"))
-        x, _ = block_full(cfg, lp, x, ctx, DEFAULT_KNOBS if knobs is None else knobs)
+        x, _ = block_full(cfg, lp, x, ctx)
         return x, jnp.float32(0.0)
 
     from .transformer import _remat
-    x, _ = scan_or_loop(_remat(body, knobs.remat), x, params["blocks"],
-                        scan=knobs.scan_layers, length=cfg.n_layers)
+    x, _ = lax.scan(_remat(body, knobs.remat), x, params["blocks"])
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    if knobs.chunked_loss:
-        ce = chunked_cross_entropy(x, params["lm_head"], batch["labels"],
-                                   cfg.vocab_size, batch.get("mask"), z_loss,
-                                   knobs.loss_chunk,
-                                   unroll=not knobs.scan_layers)
-    else:
-        logits = lm_logits(x, params["lm_head"], cfg.vocab_size)
-        ce = cross_entropy(logits, batch["labels"], batch.get("mask"), z_loss)
+    logits = lm_logits(x, params["lm_head"], cfg.vocab_size)
+    ce = cross_entropy(logits, batch["labels"], batch.get("mask"), z_loss)
     return ce, {"ce": ce, "moe_aux": jnp.float32(0.0)}
 
 
@@ -313,11 +292,10 @@ def prefill(cfg, params, batch, ctx=ShardCtx(), knobs=DEFAULT_KNOBS,
 
     def body(x, lp):
         x = ctx.constrain(x, ("act_batch", "act_seq", "act_embed"))
-        x, state = block_full(cfg, lp, x, ctx, knobs, collect_state=True)
+        x, state = block_full(cfg, lp, x, ctx, collect_state=True)
         return x, state
 
-    x, states = scan_or_loop(body, x, params["blocks"],
-                             scan=knobs.scan_layers, length=cfg.n_layers)
+    x, states = lax.scan(body, x, params["blocks"])
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x[:, -1:], params["lm_head"], cfg.vocab_size)
     cache = {"layers": states,
@@ -336,8 +314,7 @@ def decode_step(cfg, params, cache, batch, ctx=ShardCtx(),
         x, new_cache_l = block_step(cfg, lp, x, cache_l, ctx)
         return x, new_cache_l
 
-    x, new_layers = scan_or_loop(body, x, (params["blocks"], cache["layers"]),
-                                 scan=knobs.scan_layers, length=cfg.n_layers)
+    x, new_layers = lax.scan(body, x, (params["blocks"], cache["layers"]))
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x, params["lm_head"], cfg.vocab_size)
     return logits[:, 0], {"layers": new_layers, "pos": cache["pos"] + 1,

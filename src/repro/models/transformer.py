@@ -19,7 +19,6 @@ from ..configs import ModelConfig
 from ..sharding.rules import ShardCtx
 from . import attention as attn
 from .common import (
-    chunked_cross_entropy,
     cross_entropy,
     embed_tokens,
     lm_logits,
@@ -28,7 +27,7 @@ from .common import (
 )
 from .knobs import DEFAULT_KNOBS, RunKnobs
 from .moe import moe_block, moe_spec
-from .params import ParamSpec, scan_or_loop, stack
+from .params import ParamSpec, stack
 
 VISION_GRID_W = 32
 
@@ -162,9 +161,9 @@ def forward_hidden(
     experts' capacity."""
     remat = knobs.remat if remat is None else remat
     auxes, kvs = [], {}
-    for key, n, is_moe in layer_stacks(cfg):
+    for key, _, is_moe in layer_stacks(cfg):
         x, aux, kv = _run_stack(cfg, params[key], x, positions, ctx, knobs,
-                                n, is_moe, collect_kv, remat, dropless)
+                                is_moe, collect_kv, remat, dropless)
         if is_moe:               # the mean over the expert layers alone
             auxes.append(aux)
         kvs[key] = kv
@@ -173,7 +172,7 @@ def forward_hidden(
     return x, aux, kvs if collect_kv else None
 
 
-def _run_stack(cfg, blocks, x, positions, ctx, knobs, n, is_moe, collect_kv,
+def _run_stack(cfg, blocks, x, positions, ctx, knobs, is_moe, collect_kv,
                remat, dropless):
     def body(x, lp):
         x = ctx.constrain(x, ("act_batch", "act_seq", "act_embed"))
@@ -192,8 +191,7 @@ def _run_stack(cfg, blocks, x, positions, ctx, knobs, n, is_moe, collect_kv,
         return x, ys
 
     scan_body = _remat(body, remat) if not collect_kv else body
-    x, (aux, kv) = scan_or_loop(scan_body, x, blocks,
-                                scan=knobs.scan_layers, length=n)
+    x, (aux, kv) = jax.lax.scan(scan_body, x, blocks)
     return x, aux, kv
 
 
@@ -215,13 +213,8 @@ def loss_fn(
     head = _head(cfg, params)
     labels = batch["labels"]
     mask = batch.get("mask")
-    if knobs.chunked_loss:
-        ce = chunked_cross_entropy(hidden, head, labels, cfg.vocab_size,
-                                   mask, z_loss, knobs.loss_chunk,
-                                   unroll=not knobs.scan_layers)
-    else:
-        logits = lm_logits(hidden, head, cfg.vocab_size)
-        ce = cross_entropy(logits, labels, mask, z_loss)
+    logits = lm_logits(hidden, head, cfg.vocab_size)
+    ce = cross_entropy(logits, labels, mask, z_loss)
     aux_w = cfg.moe.aux_loss_weight if cfg.moe is not None else 0.0
     loss = ce + aux_w * aux
     return loss, {"ce": ce, "moe_aux": aux}
@@ -340,17 +333,15 @@ def decode_step(
     new_layers, read = {}, None
     for key, n, is_moe in layer_stacks(cfg):
         if not is_moe:
-            x, new_layers[key] = scan_or_loop(
-                dense_body, x, (params[key], cache["layers"][key]),
-                scan=knobs.scan_layers, length=n)
+            x, new_layers[key] = jax.lax.scan(
+                dense_body, x, (params[key], cache["layers"][key]))
             continue
         lp = params[key]
         experts = {w: lp["moe"][w] for w in ("w_gate", "w_up", "w_down")}
         lp = {**lp, "moe": {k: v for k, v in lp["moe"].items()
                             if k not in experts}}
-        x, (new_layers[key], n_read) = scan_or_loop(
-            moe_body(experts), x, (lp, cache["layers"][key], np.arange(n)),
-            scan=knobs.scan_layers, length=n)
+        x, (new_layers[key], n_read) = jax.lax.scan(
+            moe_body(experts), x, (lp, cache["layers"][key], np.arange(n)))
         read = n_read.sum()
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = lm_logits(x, _head(cfg, params), cfg.vocab_size)

@@ -1,6 +1,6 @@
 """Serving substrate: jit-ready prefill / decode step builders and a host
 generation loop. These are the ``serve_step`` functions the FaaS layer
-registers and the decode/long dry-run cells lower.
+registers.
 """
 from __future__ import annotations
 

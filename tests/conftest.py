@@ -3,8 +3,7 @@ import time
 import pytest
 
 # NOTE: XLA_FLAGS device-count override is deliberately NOT set here —
-# tests must see the real single CPU device (only launch/dryrun.py uses
-# the 512-device placeholder world).
+# tests must see the real single CPU device.
 
 
 @pytest.fixture

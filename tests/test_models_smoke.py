@@ -1,7 +1,7 @@
 """Per-arch smoke tests (deliverable f): every assigned architecture, as a
 REDUCED config of the same family, runs one forward/train step on CPU with
-finite outputs and correct shapes. Full configs are exercised only via the
-dry-run (ShapeDtypeStruct, no allocation)."""
+finite outputs and correct shapes. Full configs build only abstract
+params (ShapeDtypeStruct, no allocation)."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -71,21 +71,3 @@ def test_full_config_abstract_only(arch):
     specs = input_specs(cfg, list(SHAPES.values())[0])
     assert all(hasattr(s, "shape") for s in specs.values())
 
-
-@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "mamba2-370m",
-                                  "recurrentgemma-9b"])
-def test_unrolled_matches_scan(arch):
-    """scan_layers=False (roofline analysis path) must agree numerically."""
-    from repro.models.knobs import RunKnobs
-    cfg = get_reduced_config(arch)
-    model = get_model(cfg)
-    params = model.init(KEY)
-    batch = concrete_batch(cfg, SMOKE_SHAPES["train_4k"], KEY)
-    l1, _ = model.loss(params, batch,
-                       knobs=RunKnobs(q_block=32, kv_block=32,
-                                      scan_layers=True))
-    l2, _ = model.loss(params, batch,
-                       knobs=RunKnobs(q_block=32, kv_block=32,
-                                      scan_layers=False))
-    # bf16 compute: unrolled vs scan changes XLA fusion/reassociation order
-    assert abs(float(l1) - float(l2)) < 5e-3

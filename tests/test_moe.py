@@ -1,4 +1,5 @@
-"""The expert layer's two dropless routes, and the count of experts read.
+"""The expert layer's two dropless routes, the count of experts read, and
+the layer scan against a plain per-layer replay of the same layers.
 
 A product of at most ``LOOP_MAX_PAIRS`` (token, expert) pairs loops over
 the held experts that got a pair; above it the pairs go through
@@ -13,12 +14,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config
+from repro.configs import get_config, get_reduced_config
 from repro.core import spans
 from repro.core.tasks import EXPERTS_READ, Task
 from repro.models import attention as attn
 from repro.models import get_model, moe
-from repro.models.common import embed_tokens, rms_norm, swiglu
+from repro.models.common import embed_tokens, lm_logits, rms_norm, swiglu
 from repro.models.knobs import RunKnobs
 from repro.models.params import init_params
 from repro.models.transformer import build_positions, layer_stacks
@@ -28,6 +29,7 @@ from repro.sharding.rules import ShardCtx
 
 SMOKE = "deepseek-v2-lite@smoke"
 T = 32                                  # tokens: T * top_k == LOOP_MAX_PAIRS
+KNOBS = RunKnobs(q_block=8, kv_block=8)
 
 
 def _share(cfg, n_experts, top_k, held, first):
@@ -98,57 +100,93 @@ def _float32_share():
     return _share(cfg, 8, 3, 4, 2)
 
 
-def test_decode_gives_the_same_logits_with_the_layer_scan_on_and_off():
-    cfg = _float32_share()
-    model = get_model(cfg)
-    params = model.init(jax.random.PRNGKey(5))
-    toks = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0,
-                              cfg.vocab_size, jnp.int32)
-    out = {}
-    for scan in (True, False):
-        knobs = RunKnobs(q_block=8, kv_block=8, scan_layers=scan)
-        prefill = jax.jit(make_prefill(model, knobs=knobs, cache_len=12))
-        decode = jax.jit(make_decode(model, knobs=knobs))
-        _, cache = prefill(params, {"tokens": toks[:, :8]})
-        steps = []
-        for i in range(8, 12):
-            logits, cache = decode(params, cache,
-                                   {"tokens": toks[:, i:i + 1]})
-            steps.append(logits)
-        out[scan] = (np.asarray(jnp.stack(steps)), int(cache[EXPERTS_READ]))
-    np.testing.assert_allclose(out[True][0], out[False][0], rtol=0,
-                               atol=1e-5)
-    assert out[True][1] == out[False][1] > 0
-
-
-def _held_touched(cfg, params, seq, first):
-    """Replay the forward pass of ``seq`` (1, S) layer by layer, and count,
-    from each expert layer's router on the host, the held experts in the
-    top-k of every position from ``first`` on."""
+def _replay(cfg, params, seq, first=None, cache_len=None):
+    """The plain loop the layer scan must equal: run ``seq`` (B, S)
+    through the layers one at a time, each on its own slice of the stacked
+    parameters. Returns the logits at every position, each stack's prefill
+    cache (per-layer entries stacked in layer order) when ``cache_len``
+    is given, and the held experts the routers, run on the host, put in
+    the top-k of the positions from ``first`` on: per expert layer and
+    position, those any sequence's token was routed to."""
+    params = get_model(cfg).compute_params(params)
     m = cfg.moe
-    held = set(range(m.expert_first, m.expert_first + m.n_held))
-    knobs = RunKnobs(q_block=8, kv_block=8)
-    x = embed_tokens(params["embed"]["tok"], seq, jnp.float32)
+    held = set(range(m.expert_first, m.expert_first + m.n_held) if m else ())
+    x = embed_tokens(params["embed"]["tok"], seq, jnp.dtype(cfg.dtype))
     positions = build_positions(cfg, *seq.shape)
-    count = 0
+    caches, count = {}, 0
     for key, n, is_moe in layer_stacks(cfg):
+        layers = []
         for i in range(n):
             lp = jax.tree.map(lambda a: a[i], params[key])
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            x = x + attn.attn_full(cfg, lp["attn"], h, positions, ShardCtx(),
-                                   knobs)
+            a, kv = attn.attn_full(cfg, lp["attn"], h, positions, ShardCtx(),
+                                   KNOBS, return_kv=True)
+            if cache_len is not None:
+                layers.append(attn.attn_cache_from_prefill(cfg, kv,
+                                                           cache_len))
+            x = x + a
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
             if not is_moe:
                 f = lp["ffn"]
                 x = x + swiglu(h, f["w_gate"], f["w_up"], f["w_down"])
                 continue
-            logits = np.asarray(h[0], np.float64) @ np.asarray(
-                lp["moe"]["router"], np.float64)
-            top = np.argsort(-logits, -1, kind="stable")[:, :m.top_k]
-            count += sum(len(held & set(top[s])) for s in
-                         range(first, seq.shape[1]))
+            if first is not None:
+                logits = np.asarray(h, np.float64) @ np.asarray(
+                    lp["moe"]["router"], np.float64)
+                top = np.argsort(-logits, -1, kind="stable")[..., :m.top_k]
+                count += sum(len(held & set(top[:, s].ravel()))
+                             for s in range(first, seq.shape[1]))
             x = x + moe.moe_block(h, lp["moe"], cfg, ShardCtx())[0]
-    return count
+        if layers:
+            caches[key] = jax.tree.map(lambda *z: jnp.stack(z), *layers)
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    head = (params["embed"]["tok"].T if cfg.tie_embeddings
+            else params["lm_head"])
+    return lm_logits(x, head, cfg.vocab_size), caches, count
+
+
+SHARE = "deepseek-v2-lite-float32-share"
+REPLAYED = ["qwen1.5-0.5b", "qwen1.5-110b", "phi4-mini-3.8b", "minicpm3-4b",
+            "granite-moe-1b-a400m", "llama4-scout-17b-a16e", SHARE]
+
+
+@pytest.mark.parametrize("arch", REPLAYED)
+def test_the_layer_scan_matches_a_per_layer_replay(arch):
+    """Prefill's scan over the stacked layers gives the per-layer loop's
+    last-position logits and, stacked, each layer's cache, in float32."""
+    cfg = (_float32_share() if arch == SHARE
+           else get_reduced_config(arch).with_(dtype="float32"))
+    model = get_model(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    toks = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0,
+                              cfg.vocab_size, jnp.int32)
+    prefill = jax.jit(make_prefill(model, knobs=KNOBS, cache_len=16))
+    logits, cache = prefill(params, {"tokens": toks})
+    want, want_layers, _ = _replay(cfg, params, toks, cache_len=16)
+    np.testing.assert_allclose(logits, want[:, -1], rtol=0, atol=1e-5)
+    assert [key for key, _, _ in layer_stacks(cfg)] == list(want_layers)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=0,
+                                                         atol=1e-5),
+                 cache["layers"], want_layers)
+
+
+def test_cached_decode_steps_give_the_replay_and_count_its_held_experts():
+    """Four decode steps on the prefill cache of the DeepSeek share give
+    the replay's logits at each position, and read the held experts its
+    routers chose there, once per expert layer and step."""
+    cfg = _float32_share()
+    model = get_model(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    toks = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0,
+                              cfg.vocab_size, jnp.int32)
+    prefill = jax.jit(make_prefill(model, knobs=KNOBS, cache_len=12))
+    decode = jax.jit(make_decode(model, knobs=KNOBS))
+    _, cache = prefill(params, {"tokens": toks[:, :8]})
+    want, _, held = _replay(cfg, params, toks, first=8)
+    for i in range(8, 12):
+        logits, cache = decode(params, cache, {"tokens": toks[:, i:i + 1]})
+        np.testing.assert_allclose(logits, want[:, i], rtol=0, atol=1e-5)
+    assert int(cache[EXPERTS_READ]) == held > 0
 
 
 def test_serve_generate_stamps_the_held_experts_its_decode_steps_read(
@@ -182,7 +220,7 @@ def test_serve_generate_stamps_the_held_experts_its_decode_steps_read(
     seq = jnp.asarray(np.concatenate(
         [prompt, out["tokens"][:, :n_new - 1]], 1), jnp.int32)
     moe_layers = cfg.n_layers - cfg.moe.first_k_dense
-    want = _held_touched(cfg, params, seq, bucket) / (
+    want = _replay(cfg, params, seq, first=bucket)[2] / (
         (n_new - 1) * moe_layers)
     assert 0 < want < cfg.moe.top_k
     assert task.t[EXPERTS_READ] == pytest.approx(want, abs=1e-12)

@@ -1,5 +1,5 @@
 """Sharding rules: logical→mesh mapping, divisibility fallback, duplicate
-axis prevention. (Production meshes are exercised by launch/dryrun.py.)"""
+axis prevention."""
 import jax
 import jax.numpy as jnp
 import numpy as np
